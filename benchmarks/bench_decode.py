@@ -58,7 +58,7 @@ def _site_bench() -> dict:
 
     @jax.jit
     def dequant_mm(x, leaf):
-        return x @ dequant_weight(leaf)
+        return x @ dequant_weight(leaf, x.shape[-1])
 
     @jax.jit
     def kernel_mm(x, leaf):

@@ -23,6 +23,9 @@ def main(argv=None) -> None:
                     help=f"comma-separated subset of {BENCHES}")
     args = ap.parse_args(argv)
     selected = args.only.split(",") if args.only else list(BENCHES)
+    from repro.compile_cache import setup_compile_cache
+
+    setup_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0

@@ -26,6 +26,11 @@ Each worker then runs the battery:
 
 Exit code 0 only when every worker passes. CI runs this as the second
 lane of the ``mesh`` job; locally it needs nothing but a free TCP port.
+
+This is a CPU-only lane, not a chip launcher: the parent never imports
+jax, and each worker forces the CPU platform. A chip belongs to one
+process, so the chip smoke test (``chip_smoke.py``) drives every chip of
+a host from one process instead.
 """
 
 from __future__ import annotations

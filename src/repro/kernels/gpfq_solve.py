@@ -26,9 +26,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(
     w_ref,  # (K, bc) integer-domain weights
@@ -138,7 +135,7 @@ def gpfq_solve(
             pltpu.VMEM((n_tiles, block_c), jnp.float32),
             pltpu.VMEM((n_tiles, block_c), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -1,7 +1,12 @@
 """Jitted public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU (this container) and False on TPU —
-the same call sites serve tests and production.
+``interpret`` defaults to True off a TPU and False on one
+(:func:`default_interpret`), so these wrappers run anywhere. They are off
+the served path: ``repro.models.layers.packed_linear`` and the paged
+engine pick the kernel or interpret mode from an explicit backend
+(``packed_backend()``, ``resolve_paged_attn_impl``), never from this
+default, so a served run on a machine without a TPU cannot silently
+interpret the kernels it claims to compile.
 """
 
 from __future__ import annotations

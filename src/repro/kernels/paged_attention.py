@@ -45,7 +45,8 @@ watermarks against the record in interpret mode, mirroring
 
 Validated against the reference in interpret mode over shape/raggedness
 sweeps (``tests/test_paged_attention.py``) — the same testing pattern as
-``w4a8_mm``. Compiled-mode perf is a TPU-hardware question (ROADMAP).
+``w4a8_mm`` — and compiled for a described TPU v5e over float and int8
+pages (``tests/test_tpu_compile.py``).
 """
 
 from __future__ import annotations
@@ -58,8 +59,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+#: f32 adds integers exactly below 2^24, so an integer register of at most
+#: this many signed bits is exact when the MXU accumulates it in f32
+_F32_EXACT_BITS = 24
 
 
 def _softcap(scores, cap):
@@ -142,7 +144,9 @@ def paged_attention_reference(q, k_pages, v_pages, block_table, seq_lens, *,
     valid = jnp.arange(k.shape[1])[None, :] < seq_lens[:, None]  # (B, P*bs)
     s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    out = jnp.einsum("bkgs,bskd->bkgd", p, v)
+    # dequantized int8 pages are f32: the output keeps the query's dtype,
+    # as the kernel's does
+    out = jnp.einsum("bkgs,bskd->bkgd", p, v).astype(q.dtype)
     return out.reshape(B, nh, hd)
 
 
@@ -211,16 +215,9 @@ def _kernel(tab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
 
 def _register_check(watermark, p_bits: int, what: str):
     """Interpret-mode verification that an integer register watermark stays
-    inside its certified P-bit range (the w4a8_mm ``assert_inner`` idiom,
-    pl.debug_check with a host-assert fallback for older pallas)."""
+    inside its certified P-bit range (the w4a8_mm ``assert_inner`` idiom)."""
     limit = 2 ** (p_bits - 1) - 1
-    if hasattr(pl, "debug_check"):
-        pl.debug_check(watermark <= limit, f"{what} accumulator overflow")
-    else:  # pragma: no cover - older pallas releases
-        def _check(w, lim=limit, name=what):
-            assert int(w) <= lim, f"{name} accumulator overflow: {w} > {lim}"
-
-        jax.debug.callback(_check, watermark)
+    pl.debug_check(watermark <= limit, f"{what} accumulator overflow")
 
 
 def _quant_kernel(tab_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
@@ -228,11 +225,17 @@ def _quant_kernel(tab_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
                   hd: int, n_pages: int, softcap, out_dtype, spec,
                   assert_bounds: bool):
     """The int8-KV body: same online-softmax recurrence as :func:`_kernel`,
-    but both reductions run in the integer domain the ``spec``
+    but both reductions run over integer codes the ``spec``
     (:class:`~repro.quant.spec.AttnDatapathSpec`) certifies — QK^T as an
     hd-deep q-code × k-code dot in a P_qk-bit register, PV as a per-page
     block_size-deep prob-code × v-code dot in a P_pv-bit register, with
-    scales applied once per page on the way into the float outer state."""
+    scales applied once per page on the way into the float outer state.
+
+    The codes ride the MXU as bf16 with f32 accumulation: bf16 holds every
+    integer up to 256 (prob codes reach 255, which int8 cannot carry) and
+    f32 sums integers exactly below 2^24, which P_qk, P_pv <= 24 guarantees
+    (checked when the kernel is built) — so each dot is the exact integer
+    register value the record certifies."""
     b, j = pl.program_id(0), pl.program_id(1)
     nh = nkv * g
     _init_softmax_state(j, m_ref, l_ref, acc_ref)
@@ -241,18 +244,19 @@ def _quant_kernel(tab_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     # per-head symmetric quantization of the query rows (the A-side codes)
     q_amax = jnp.max(jnp.abs(q), axis=-1, keepdims=True)  # (nh, 1)
     q_scale = jnp.maximum(q_amax / spec.q_qmax, 1e-8)
-    q_codes = jnp.clip(jnp.rint(q / q_scale), -spec.q_qmax,
-                       spec.q_qmax).astype(jnp.int32)
-    k_codes = k_ref[0].astype(jnp.int32)  # (bs, nkv, hd) int8 codes
-    k_scale = ks_ref[0]  # (nkv,) f32 — this page's per-head scale
+    q_codes = jnp.clip(jnp.rint(q / q_scale), -spec.q_qmax, spec.q_qmax)
+    k_codes = k_ref[0].astype(jnp.float32)  # (bs, nkv, hd) int8 codes
+    k_scale = ks_ref[0]  # (nkv, 1) f32 — this page's per-head scale
 
     # hd-deep integer QK^T dot, held in the P_qk register
-    s_int = jnp.einsum("kgd,skd->kgs", q_codes.reshape(nkv, g, hd), k_codes,
-                       preferred_element_type=jnp.int32)
+    s_int = jnp.einsum("kgd,skd->kgs",
+                       q_codes.reshape(nkv, g, hd).astype(jnp.bfloat16),
+                       k_codes.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
     if assert_bounds:
         _register_check(jnp.max(jnp.abs(s_int)), spec.p_qk, "QK^T")
-    s = (s_int.astype(jnp.float32) * q_scale.reshape(nkv, g, 1)
-         * k_scale[:, None, None])
+    s = (s_int * q_scale.reshape(nkv, g, 1)
+         * k_scale[:, :, None])
     s = _softcap(s / math.sqrt(hd), softcap)
     s = _mask_scores(s, j, b, lens_ref, bs, nh)
 
@@ -260,20 +264,20 @@ def _quant_kernel(tab_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         # probability codes (unsigned prob_bits) — the PV A-side operand;
         # the normalizer accumulates the *quantized* probabilities so the
         # final weighted average stays consistent with the PV numerator
-        p_codes = jnp.rint(p * spec.prob_qmax).astype(jnp.int32)
-        v_codes = v_ref[0].astype(jnp.int32)  # (bs, nkv, hd)
-        v_scale = vs_ref[0]  # (nkv,)
+        p_codes = jnp.rint(p * spec.prob_qmax)  # (nh, bs), 0..prob_qmax
+        v_codes = v_ref[0].astype(jnp.float32)  # (bs, nkv, hd)
+        v_scale = vs_ref[0]  # (nkv, 1)
         # per-page block_size-deep integer PV dot, held in the P_pv
         # register — the page is the tile; partials drain scaled into the
         # f32 outer accumulator
-        pv_int = jnp.einsum("kgs,skd->kgd", p_codes.reshape(nkv, g, bs),
-                            v_codes, preferred_element_type=jnp.int32)
+        pv_int = jnp.einsum("kgs,skd->kgd",
+                            p_codes.reshape(nkv, g, bs).astype(jnp.bfloat16),
+                            v_codes.astype(jnp.bfloat16),
+                            preferred_element_type=jnp.float32)
         if assert_bounds:
             _register_check(jnp.max(jnp.abs(pv_int)), spec.p_pv, "PV")
-        pv = pv_int.astype(jnp.float32) * (v_scale[:, None, None]
-                                           / spec.prob_qmax)
-        return (p_codes.astype(jnp.float32) / spec.prob_qmax,
-                pv.reshape(nh, hd))
+        pv = pv_int * (v_scale[:, :, None] / spec.prob_qmax)
+        return p_codes / spec.prob_qmax, pv.reshape(nh, hd)
 
     _softmax_accumulate(s, m_ref, l_ref, acc_ref, pv_of)
     _finalize_output(j, n_pages, o_ref, m_ref, l_ref, acc_ref, out_dtype)
@@ -322,15 +326,22 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens, *,
     operands = [block_table, seq_lens, q, k_pages, v_pages]
     if quantized:
         def scale_idx(b, j, tab, lens):
-            return (jnp.minimum(tab[b, j], nb - 1), 0)
+            return (jnp.minimum(tab[b, j], nb - 1), 0, 0)
 
-        in_specs += [pl.BlockSpec((1, nkv), scale_idx)] * 2
-        operands += [k_scales.astype(jnp.float32),
-                     v_scales.astype(jnp.float32)]
+        # (nb, nkv, 1): the block's last two dims are the array's own (a
+        # legal block), and the kernel reads the scales one head per sublane
+        in_specs += [pl.BlockSpec((1, nkv, 1), scale_idx)] * 2
+        operands += [k_scales.astype(jnp.float32).reshape(nb, nkv, 1),
+                     v_scales.astype(jnp.float32).reshape(nb, nkv, 1)]
         derived = AttnDatapathSpec.for_cache(
             hd, bs, kv_bits=8 * k_pages.dtype.itemsize)
         if attn_spec is not None:
             derived.require_matches(attn_spec, context="paged_decode_attention")
+        if max(derived.p_qk, derived.p_pv) > _F32_EXACT_BITS:
+            raise ValueError(
+                f"int8 paged attention accumulates its integer registers in "
+                f"f32, exact only up to {_F32_EXACT_BITS} bits; this cache "
+                f"needs P_qk={derived.p_qk}, P_pv={derived.p_pv}")
         kernel = functools.partial(
             _quant_kernel, bs=bs, nkv=nkv, g=g, hd=hd, n_pages=n_pages,
             softcap=softcap, out_dtype=q.dtype, spec=derived,
@@ -357,7 +368,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nh, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -15,9 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 
 def _kernel(x_ref, g_ref, qp_ref, out_ref, *, eps: float, qmax: int):
     x = x_ref[...].astype(jnp.float32)  # (bm, D)
@@ -58,7 +55,7 @@ def quant_rmsnorm(
         ],
         out_specs=pl.BlockSpec((block_m, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((m, d), jnp.uint8),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",),
         ),
         interpret=interpret,

@@ -4,9 +4,13 @@ AXE certifies (paper §3.3 / §4.2), as a Pallas TPU kernel.
 Datapath (Figure 2 of the paper, mapped to the TPU memory hierarchy):
 
   * weights arrive int4-PACKED (two codes per int8 byte along K) — half the
-    HBM->VMEM traffic of int8 weights;
-  * activations arrive as int8 codes (asymmetric, zero-point handled by a
-    per-channel correction term computed once outside the kernel);
+    HBM->VMEM traffic of int8 weights — and are unpacked in VMEM with int32
+    shifts (Mosaic legalizes no int8 shift) into an int8 MXU operand;
+  * activations arrive as 8-bit codes (asymmetric, zero-point handled by a
+    per-channel correction term computed once outside the kernel). Unsigned
+    codes are shifted by -128 into int8 outside the kernel, so the MXU sees
+    int8 x int8 with int32 accumulation; ``128 * col_sums`` folds into the
+    zero-point term, which keeps the result exact;
   * the K axis is processed in tiles of T = ``block_k`` (128 = one MXU pass,
     the paper's T): each tile's dot product is the *inner* accumulator —
     AXE guarantees it fits P_I bits (16 in the LLM recipe), which is what
@@ -17,9 +21,25 @@ Datapath (Figure 2 of the paper, mapped to the TPU memory hierarchy):
     writes bf16/f32.
 
 Validated against ref.py in interpret mode over shape/dtype sweeps
-(tests/test_kernels.py); the ``assert_inner`` flag additionally checks the
-P_I bound *inside* the kernel on every tile (interpret mode only — on
-hardware the bound is a theorem, not a runtime check).
+(tests/test_kernels.py) and compiled for a described TPU v5e
+(tests/test_tpu_compile.py); the ``assert_inner`` flag additionally checks
+the certified *unsigned-code* P_I partial inside the kernel on every tile
+(interpret mode only — on hardware the bound is a theorem, not a runtime
+check).
+
+Alignment: on the chip every K and N block is a multiple of the 128-lane
+width. Packed serving leaves are zero-padded to that width at pack time
+(:func:`pad_packed`); any other operand is padded here at the call and the
+output sliced back. Zero codes add nothing to a tile partial or to
+``col_sums``, and the certified tiles start at K = 0, so every 128-tile holds
+the nonzeros it was certified on. A block that is not lane-aligned raises
+instead of being silently shrunk.
+
+In-kernel unpack order: a packed block row ``r`` holds K rows ``2r`` (low
+nibble) and ``2r + 1`` (high nibble). The kernel stacks all low nibbles
+above all high nibbles (no interleave across sublanes), and the wrapper
+permutes each K tile of the activation codes to the same [even | odd]
+order — the same products, summed over the same tile.
 
 Two shape regimes share the kernel body:
 
@@ -36,15 +56,14 @@ Two shape regimes share the kernel body:
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams; support both
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
+#: TPU lane width: every K / N block of the compiled kernel is a multiple
+LANE = 128
 
 
 def unpack_int4(packed: jax.Array) -> jax.Array:
@@ -137,38 +156,71 @@ def _expand_sparse24_block(wp, meta):
     return dense.reshape(g4 * 4, bn)
 
 
+def _unpack_tile(wp):
+    """(bk//2, bn) packed int8 block -> (bk, bn) int8 codes in split order:
+    rows [0, bk/2) are the low nibbles (even K of the tile), rows
+    [bk/2, bk) the high nibbles (odd K). int32 shifts sign-extend each
+    nibble; the result narrows to int8 for the MXU."""
+    w = wp.astype(jnp.int32)
+    low = jnp.right_shift(jnp.left_shift(w, 28), 28)
+    high = jnp.right_shift(w, 4)
+    return jnp.concatenate([low, high], axis=0).astype(jnp.int8)
+
+
+def _split_tiles(x, bk: int):
+    """Permute each bk-wide K tile of (M, K) codes to [even | odd] order —
+    the row order :func:`_unpack_tile` produces. Tile membership is
+    unchanged, so every tile partial sums the same products."""
+    m, k = x.shape
+    return x.reshape(m, k // bk, bk // 2, 2).swapaxes(-1, -2).reshape(m, k)
+
+
+def _signed_codes(x):
+    """8-bit activation codes -> (int8 codes, shift) with x = codes + shift:
+    unsigned codes move down by 128 so the MXU runs int8 x int8."""
+    if x.dtype == jnp.uint8:
+        return (x.astype(jnp.int32) - 128).astype(jnp.int8), 128
+    if x.dtype == jnp.int8:
+        return x, 0
+    raise TypeError(f"activation codes must be uint8 or int8, got {x.dtype}")
+
+
+def _check_inner(partial, w, shift: int, p_inner: int):
+    """Interpret-mode check of the certified P_I bound on the *unsigned*
+    code partial (the shifted MXU partial plus ``shift * sum_k w``)."""
+    if shift:
+        partial = partial + shift * jnp.sum(
+            w.astype(jnp.int32), axis=0, keepdims=True)
+    limit = 2 ** (p_inner - 1) - 1
+    pl.debug_check(jnp.max(jnp.abs(partial)) <= limit,
+                   "inner accumulator overflow")
+
+
 def _kernel(x_ref, wp_ref, sw_ref, corr_ref, out_ref, acc_ref, *,
-            n_k: int, p_inner: int, assert_inner: bool, out_dtype):
+            n_k: int, p_inner: int, assert_inner: bool, shift: int,
+            out_dtype):
     k = pl.program_id(2)
 
     @pl.when(k == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.int32)  # (bm, bk) int8 codes
-    w = unpack_int4(wp_ref[...]).astype(jnp.int32)  # (bk, bn)
+    w = _unpack_tile(wp_ref[...])  # (bk, bn) int8, split order
     # inner accumulator: one K-tile MAC — AXE certifies |partial| < 2^(P_I-1)
     partial = jax.lax.dot_general(
-        x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
+        x_ref[...], w, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32,
     )
     if assert_inner:  # interpret-mode verification of the paper's guarantee
-        limit = 2 ** (p_inner - 1) - 1
-        watermark = jnp.max(jnp.abs(partial))
-        if hasattr(pl, "debug_check"):
-            pl.debug_check(watermark <= limit, "inner accumulator overflow")
-        else:  # older pallas: host-side assert (interpret mode only)
-            def _check(w, lim=limit):
-                assert int(w) <= lim, f"inner accumulator overflow: {w} > {lim}"
-
-            jax.debug.callback(_check, watermark)
+        _check_inner(partial, w, shift, p_inner)
     # outer accumulator (P_O of Eq. 22)
     acc_ref[...] += partial
 
     @pl.when(k == n_k - 1)
     def _epilogue():
         acc = acc_ref[...].astype(jnp.float32)
-        # zero-point correction (zp * sum_k q[k,n], precomputed per channel)
-        # then the fused dequant scale s_x * s_w[n]
+        # zero-point correction ((zp - shift) * sum_k q[k,n], precomputed per
+        # channel) then the fused dequant scale s_x * s_w[n]
         out_ref[...] = ((acc - corr_ref[...]) * sw_ref[...]).astype(out_dtype)
 
 
@@ -186,12 +238,47 @@ def _round_up(x: int, mult: int) -> int:
     return -(-x // mult) * mult
 
 
-def _fit_block(dim: int, pref: int) -> int:
-    """Largest block <= pref that divides dim (pref itself when it divides)."""
-    if dim % pref == 0:
-        return pref
-    g = math.gcd(dim, pref)
-    return g if g else dim
+def pad_packed(packed: jax.Array) -> jax.Array:
+    """Zero-pad a (..., K//2, N) packed leaf so K and N are multiples of
+    :data:`LANE` — the pack-time half of the kernel's alignment contract
+    (zero codes contribute nothing; the call slices the output back to the
+    leaf's logical N, which its ``scale`` carries)."""
+    *lead, k2, n = packed.shape
+    pk = _round_up(2 * k2, LANE) // 2 - k2
+    pn = _round_up(n, LANE) - n
+    if not (pk or pn):
+        return packed
+    return jnp.pad(packed, [(0, 0)] * len(lead) + [(0, pk), (0, pn)])
+
+
+def _pick_bm(m: int, block_m: int) -> int:
+    """M block: one sub-block_m block (rounded up to the 8-row sublane) in
+    the decode regime; otherwise the largest power-of-two shrink of block_m
+    whose ragged-tail padding stays small (<= max(bm/4, 8) rows) instead of
+    paying up to a whole extra block of wasted MXU work (m=130 with bm=128
+    would pad to 256; an 8-row block pads to 136)."""
+    if m <= block_m:
+        return _round_up(m, 8)
+    c = block_m
+    while c >= 8:
+        if _round_up(m, c) - m <= max(c // 4, 8):
+            return c
+        c //= 2
+    return 8
+
+
+def _check_lanes(bk: int, bn: int, interpret: bool) -> None:
+    """Compiled kernels take lane-aligned K and N blocks only — an
+    unaligned block is an error, never a silently shrunk divisor."""
+    if not interpret and (bk % LANE or bn % LANE):
+        raise ValueError(
+            f"W4A8 kernel blocks must be multiples of {LANE} lanes on the "
+            f"chip, got block_k={bk} block_n={bn}")
+
+
+def _pad_to(a: jax.Array, shape: tuple[int, ...]) -> jax.Array:
+    pads = [(0, t - s) for s, t in zip(a.shape, shape)]
+    return jnp.pad(a, pads) if any(p for _, p in pads) else a
 
 
 @functools.partial(
@@ -200,9 +287,9 @@ def _fit_block(dim: int, pref: int) -> int:
                      "assert_inner", "interpret", "out_dtype"),
 )
 def w4a8_matmul(
-    x_int8: jax.Array,  # (M, K) int8 activation codes
-    w_packed: jax.Array,  # (K//2, N) int8 packed int4 weights
-    w_scale: jax.Array,  # (N,) f32 per-channel weight scales
+    x_int8: jax.Array,  # (M, K) uint8 / int8 activation codes
+    w_packed: jax.Array,  # (K'//2, N') int8 packed int4 weights, K' >= K, N' >= N
+    w_scale: jax.Array,  # (N,) f32 per-channel weight scales (logical N)
     act_scale: float,
     act_zp: int,
     *,
@@ -216,31 +303,11 @@ def w4a8_matmul(
     col_sums: jax.Array | None = None,  # (N,) or (1, N) int32, pack-time
 ):
     m, k = x_int8.shape
-    k2, n = w_packed.shape
-    assert k == 2 * k2, (x_int8.shape, w_packed.shape)
-
-    # Ragged shapes: M is padded with zero rows (garbage rows sliced off
-    # after the call — the zero-point correction makes them nonzero, but
-    # they are never read); N and K fall back to the largest divisor block.
-    if m <= block_m:
-        bm = _round_up(m, 8)  # decode regime: one sub-block_m M block
-    else:
-        # prefill regime with a ragged tail: shrink the M block until the
-        # zero-row padding is small (<= max(bm/4, 8) rows) instead of
-        # paying up to a whole extra block of wasted MXU work (m=130 with
-        # bm=128 would pad to 256; an 8-row block pads to 136)
-        bm, c = 8, block_m
-        while c >= 8:
-            if _round_up(m, c) - m <= max(c // 4, 8):
-                bm = c
-                break
-            c //= 2
-    bn = _fit_block(n, block_n)
-    bk = _fit_block(k, block_k)
-    assert bk % 2 == 0, f"K tile {bk} must be even for packed int4 (K={k})"
-    m_pad = _round_up(m, bm)
-    if m_pad != m:
-        x_int8 = jnp.pad(x_int8, ((0, m_pad - m), (0, 0)))
+    k2, n_w = w_packed.shape
+    n = w_scale.size
+    assert k <= 2 * k2 and n <= n_w, (x_int8.shape, w_packed.shape, n)
+    _check_lanes(block_k, block_n, interpret)
+    assert block_k % 2 == 0, f"K tile {block_k} must be even for packed int4"
 
     # per-channel zero-point correction: zp * sum_k q[k, n] (int32), and the
     # fused dequant scale s_x * s_w — both computed once outside the kernel.
@@ -248,16 +315,32 @@ def w4a8_matmul(
     # unpack here is the prefill/one-off path.
     if col_sums is None:
         col_sums = jnp.sum(unpack_int4(w_packed).astype(jnp.int32), axis=0)
-    corr = (col_sums.reshape(-1).astype(jnp.float32) * act_zp)[None, :]  # (1, N)
-    sw = (w_scale.reshape(-1).astype(jnp.float32) * act_scale)[None, :]  # (1, N)
+    col_sums = col_sums.reshape(-1)[:n]
+    codes, shift = _signed_codes(x_int8)
+    corr = (col_sums.astype(jnp.float32) * (act_zp - shift))[None, :]
+    sw = (w_scale.reshape(-1).astype(jnp.float32) * act_scale)[None, :]
 
-    n_k = k // bk
-    grid = (m_pad // bm, n // bn, n_k)
+    # Ragged shapes: M pads with zero rows (garbage rows sliced off after
+    # the call — the zero-point correction makes them nonzero, but they are
+    # never read); K and N pad with zero codes to whole blocks (a no-op for
+    # leaves padded at pack time).
+    bm, bk, bn = _pick_bm(m, block_m), block_k, block_n
+    m_pad = _round_up(m, bm)
+    k_pad = _round_up(2 * k2, bk)
+    n_pad = _round_up(n_w, bn)
+    codes = _split_tiles(_pad_to(codes, (m_pad, k_pad)), bk)
+    w_packed = _pad_to(w_packed, (k_pad // 2, n_pad))
+    corr = _pad_to(corr, (1, n_pad))
+    sw = _pad_to(sw, (1, n_pad))
+
+    n_k = k_pad // bk
+    grid = (m_pad // bm, n_pad // bn, n_k)
     kernel = functools.partial(
         _kernel,
         n_k=n_k,
         p_inner=p_inner,
         assert_inner=assert_inner,
+        shift=shift,
         out_dtype=out_dtype,
     )
     out = pl.pallas_call(
@@ -270,19 +353,19 @@ def w4a8_matmul(
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m_pad, n), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
-    )(x_int8, w_packed, sw, corr)
-    return out[:m] if m_pad != m else out
+    )(codes, w_packed, sw, corr)
+    return out[:m, :n]
 
 
 def w4a8_decode_matmul(
     x_int8: jax.Array,  # (B, K) activation codes — M = decode batch
-    w_packed: jax.Array,  # (K//2, N)
+    w_packed: jax.Array,  # (K'//2, N')
     w_scale: jax.Array,  # (N,) or (1, N)
     col_sums: jax.Array,  # (N,) or (1, N) int32 — REQUIRED, from pack time
     act_scale,
@@ -316,7 +399,7 @@ def _sparse_kernel(x_ref, wp_ref, meta_ref, sw_ref, corr_ref, out_ref, acc_ref, 
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...].astype(jnp.int32)  # (bm, bk) int8 codes
+    x = x_ref[...].astype(jnp.int32)  # (bm, bk) 8-bit codes
     # expand the compressed block in VMEM: the HBM->VMEM weight traffic is
     # bk/4 + bk/4 bytes per column (codes + metadata) instead of bk/2 dense
     w = _expand_sparse24_block(wp_ref[...], meta_ref[...])  # (bk, bn) int32
@@ -324,15 +407,7 @@ def _sparse_kernel(x_ref, wp_ref, meta_ref, sw_ref, corr_ref, out_ref, acc_ref, 
         x, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
     )
     if assert_inner:  # interpret-mode verification (2:4 tightens the bound)
-        limit = 2 ** (p_inner - 1) - 1
-        watermark = jnp.max(jnp.abs(partial))
-        if hasattr(pl, "debug_check"):
-            pl.debug_check(watermark <= limit, "inner accumulator overflow")
-        else:
-            def _check(w, lim=limit):
-                assert int(w) <= lim, f"inner accumulator overflow: {w} > {lim}"
-
-            jax.debug.callback(_check, watermark)
+        _check_inner(partial, w, 0, p_inner)
     acc_ref[...] += partial
 
     @pl.when(k == n_k - 1)
@@ -370,36 +445,35 @@ def w4a8_sparse_matmul(
     float math in the same order. ``col_sums`` must be the dense codes'
     per-channel sums (= the sums of the kept codes — zeros add nothing).
 
-    Same ragged-M handling as the dense kernel; decode batches (M < 8)
-    round up to the 8-row sublane.
+    Same ragged-shape handling as the dense kernel; decode batches (M < 8)
+    round up to the 8-row sublane. This kernel is validated in interpret
+    mode only: it keeps the interleaved unpack and an int32 MXU dot.
     """
-    m, k4 = x_int8.shape[0], w_packed.shape[0]
-    k = 4 * k4
-    assert x_int8.shape[1] == k, (x_int8.shape, w_packed.shape)
+    m, k = x_int8.shape
+    k4, n_w = w_packed.shape
+    n = w_scale.size
+    assert k <= 4 * k4 and n <= n_w, (x_int8.shape, w_packed.shape, n)
     assert w_meta.shape == w_packed.shape, (w_meta.shape, w_packed.shape)
-    n = w_packed.shape[1]
+    _check_lanes(block_k, block_n, interpret)
+    assert block_k % 4 == 0, f"K tile {block_k} must be a multiple of 4 for 2:4 codes"
 
-    if m <= block_m:
-        bm = _round_up(m, 8)
-    else:
-        bm, c = 8, block_m
-        while c >= 8:
-            if _round_up(m, c) - m <= max(c // 4, 8):
-                bm = c
-                break
-            c //= 2
-    bn = _fit_block(n, block_n)
-    bk = _fit_block(k, block_k)
-    assert bk % 4 == 0, f"K tile {bk} must be a multiple of 4 for 2:4 codes (K={k})"
+    # same ragged handling as the dense kernel: zero rows / zero codes (a
+    # zero metadata byte points both kept slots at position 0 with value 0)
+    bm, bk, bn = _pick_bm(m, block_m), block_k, block_n
     m_pad = _round_up(m, bm)
-    if m_pad != m:
-        x_int8 = jnp.pad(x_int8, ((0, m_pad - m), (0, 0)))
+    k_pad = _round_up(4 * k4, bk)
+    n_pad = _round_up(n_w, bn)
+    x_int8 = _pad_to(x_int8, (m_pad, k_pad))
+    w_packed = _pad_to(w_packed, (k_pad // 4, n_pad))
+    w_meta = _pad_to(w_meta, (k_pad // 4, n_pad))
 
-    corr = (col_sums.reshape(-1).astype(jnp.float32) * act_zp)[None, :]
+    corr = (col_sums.reshape(-1)[:n].astype(jnp.float32) * act_zp)[None, :]
     sw = (w_scale.reshape(-1).astype(jnp.float32) * act_scale)[None, :]
+    corr = _pad_to(corr, (1, n_pad))
+    sw = _pad_to(sw, (1, n_pad))
 
-    n_k = k // bk
-    grid = (m_pad // bm, n // bn, n_k)
+    n_k = k_pad // bk
+    grid = (m_pad // bm, n_pad // bn, n_k)
     kernel = functools.partial(
         _sparse_kernel,
         n_k=n_k,
@@ -418,14 +492,14 @@ def w4a8_sparse_matmul(
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((m_pad, n), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((m_pad, n_pad), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
     )(x_int8, w_packed, w_meta, sw, corr)
-    return out[:m] if m_pad != m else out
+    return out[:m, :n]
 
 
 def w4a8_sparse_decode_matmul(

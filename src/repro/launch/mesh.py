@@ -14,13 +14,9 @@ import jax
 
 
 def _mk(shape, axes):
-    try:
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    except (AttributeError, TypeError):
-        # older jax: no AxisType / no axis_types kwarg — Auto is the default
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -16,6 +16,7 @@ from dataclasses import replace
 import jax
 
 from repro.checkpoint import CheckpointManager, save_pytree
+from repro.compile_cache import setup_compile_cache
 from repro.configs import get_config, get_smoke
 from repro.core import PTQConfig
 from repro.data import DataConfig, TokenBatcher
@@ -25,7 +26,7 @@ from repro.quant.pipeline import float_ppl, quantized_ppl
 from repro.quant.serve_packed import export_quantized_artifact
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -48,9 +49,21 @@ def main(argv=None):
     ap.add_argument("--eval-batches", type=int, default=4)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--out", type=str, default=None)
-    args = ap.parse_args(argv)
+    return ap
 
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
+    setup_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
+    return quantize(cfg, args)
+
+
+def quantize(cfg, args) -> dict:
+    """Calibrate, quantize and certify ``cfg`` under the options ``args``
+    (from :func:`build_parser`); print the JSON report, write the v2
+    artifact under ``args.out/quantized`` when ``--out`` is given, and
+    return the report."""
     data = TokenBatcher(
         DataConfig(vocab=cfg.vocab, seq_len=args.seq,
                    global_batch=args.calib_batch_size, seed=args.seed)

@@ -42,6 +42,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint import CheckpointManager
+from repro.compile_cache import setup_compile_cache
 from repro.configs import get_config, get_smoke
 from repro.data import DataConfig, TokenBatcher
 from repro.models.layers import use_packed_backend
@@ -135,6 +136,7 @@ def main(argv=None):
                          "generation; the decode jaxpr gains only debug "
                          "callbacks (structurally asserted)")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     params = init_model(jax.random.key(args.seed), cfg)

@@ -150,8 +150,11 @@ def is_dequant_site(v) -> bool:
     return isinstance(v, dict) and "w" in v and "packed" not in v
 
 
-def dequant_weight(leaf):
-    """In-graph dequantization of a packed leaf (the fallback datapath).
+def dequant_weight(leaf, k: int):
+    """In-graph dequantization of a packed leaf (the fallback datapath) to
+    its logical (..., k, N) weight: dense codes are stored zero-padded to
+    whole 128-lane blocks, so the rows past the activation's depth ``k`` and
+    the columns past the ``scale`` leaf's N are sliced off.
 
     2:4 sparse-compressed leaves (a ``meta`` index leaf beside the packed
     codes) expand through the gather reference — bit-identical integer
@@ -162,6 +165,7 @@ def dequant_weight(leaf):
         q = unpack_sparse24(leaf["packed"], leaf["meta"])
     else:
         q = unpack_int4(leaf["packed"])
+    q = q[..., :k, :leaf["scale"].shape[-1]]
     return q.astype(leaf["scale"].dtype) * leaf["scale"]
 
 
@@ -241,7 +245,9 @@ def packed_linear(x, leaf, *, spec=None, assert_inner: bool = False):
 
     backend = packed_backend()
     if backend == "dequant":
-        y = x @ dequant_weight(leaf)
+        # calibrated leaves carry f32 scales: the product comes back in the
+        # activation dtype, as on the kernel path
+        y = (x @ dequant_weight(leaf, x.shape[-1])).astype(x.dtype)
         if "bias" in leaf:
             y = y + leaf["bias"].reshape(-1).astype(y.dtype)
         return y
@@ -393,16 +399,17 @@ def init_attention(key, cfg: ModelConfig):
     }
 
 
-def resolve_weight(params, name):
+def resolve_weight(params, name, k: int):
     """Weight accessor that transparently dequantizes packed-int4 leaves
-    (the W4A8 serving artifact — see repro.quant.serve_packed). Call sites
+    (the W4A8 serving artifact — see repro.quant.serve_packed) to the
+    logical reduction depth ``k``. Call sites
     that are plain matmuls should prefer :func:`pmm`, which can route the
     packed leaf through the fused w4a8_mm kernel instead of materializing
     the full-width weight; resolve_weight remains for consumers that need
     the dense array (einsums, analysis, the dequant fallback)."""
     v = params[name]
     if is_packed(v):
-        return dequant_weight(v)
+        return dequant_weight(v, k)
     if is_dequant_site(v):
         # NOTE: the dense weight only — callers needing the corrected bias
         # (pmm, moe._expert_matmul) apply it at the matmul

@@ -112,7 +112,9 @@ def _expert_matmul(params, name, xe):
 
     leaf = params[name]
     if not (is_packed(leaf) and packed_backend() != "dequant"):
-        out = jnp.einsum("egcd,edf->egcf", xe, resolve_weight(params, name))
+        out = jnp.einsum("egcd,edf->egcf", xe,
+                         resolve_weight(params, name, xe.shape[-1]))
+        out = out.astype(xe.dtype)
         if (is_packed(leaf) or is_dequant_site(leaf)) and "bias" in leaf:
             # calibrated artifacts carry the bias-corrected bias (E, 1, C);
             # apply it here too so both backends compute the same function

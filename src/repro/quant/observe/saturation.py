@@ -144,7 +144,10 @@ def _leaf_watermark(leaf, code_min: float, code_max: float) -> dict:
     spec = leaf_datapath(leaf)
     if spec is None:
         return {}
+    # the padded columns past the scale's logical N hold zero codes
+    n = leaf["scale"].shape[-1]
     w = np.asarray(jax.device_get(unpack_int4(leaf["packed"])), np.float64)
+    w = w[..., :n]
     k = w.shape[-2]
     w = w.reshape(-1, k, w.shape[-1])  # fold repeat/expert stacking
     t = spec.tile if spec.tile else k

@@ -293,7 +293,8 @@ def _calibrate_component(
             stats = LayerStats(k=spec.k)
             stats.update(_flat(sa), _flat(sq))
             stats_cache.append((sa, sq, stats))
-        w = _weight_at(p, spec.path)
+        # the solvers run in f32 whatever the model's param dtype
+        w = _weight_at(p, spec.path).astype(jnp.float32)
         override = plan.get(site_prefix + name) if plan else None
         ql = quantize_linear(w, stats, _site_ptq(ptq, spec, override))
         ql.aux["observer"] = stats.observer

@@ -40,8 +40,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.w4a8_mm import (
+    LANE,
+    _round_up,
     compress_2to4,
     pack_int4,
+    pad_packed,
     unpack_int4,
     unpack_sparse24,
 )
@@ -113,7 +116,9 @@ def _rtn_codes(w: jax.Array, w_bits: int) -> tuple[jax.Array, jax.Array]:
 
 def _pack_leaf(w: jax.Array, spec: DatapathSpec | None = None) -> dict:
     """(..., K, N) -> packed int4 + per-channel scale (stack-aware: leading
-    repeat/expert axes pass straight through). ``col_sums`` is the
+    repeat/expert axes pass straight through). Dense codes are zero-padded
+    to whole 128-lane K and N blocks (``pad_packed``; the logical N is the
+    ``scale`` leaf's, the logical K the activation's). ``col_sums`` is the
     per-channel sum of int4 codes over K, precomputed here once so the
     decode kernel's zero-point correction never needs a full
     ``unpack_int4`` of the weights at serving time (repro.kernels.w4a8_mm
@@ -159,7 +164,7 @@ def _pack_leaf(w: jax.Array, spec: DatapathSpec | None = None) -> dict:
             "spec_arr": _spec_arr_leaf(spec, lead),
         }
     return {
-        "packed": pack_int4(q),
+        "packed": pad_packed(pack_int4(q)),
         "scale": scale.astype(jnp.bfloat16),
         "col_sums": jnp.sum(q, axis=-2, keepdims=True).astype(jnp.int32),
         "spec": spec,
@@ -271,7 +276,7 @@ def _site_rec_leaf(recs: list[dict], site: SiteSpec, name: str):
             )
         packed_codes, meta = compress_2to4(q)
     else:
-        packed_codes, meta = pack_int4(q), None
+        packed_codes, meta = pad_packed(pack_int4(q)), None
     leaf = {
         "packed": packed_codes,
         "scale": jnp.stack([jnp.asarray(r["scale"], jnp.float32) for r in recs]),
@@ -425,7 +430,12 @@ def load_flat_artifact(directory: str) -> tuple[dict, dict]:
         # keystr of a flat string key: "['layer0/mixer.wq/q']"
         if name.startswith("['") and name.endswith("']"):
             name = name[2:-2]
-        flat[name] = np.load(os.path.join(directory, entry["file"]))
+        arr = np.load(os.path.join(directory, entry["file"]))
+        if arr.dtype.kind == "V":
+            # np.save keeps bfloat16 leaves (the norms of a bf16 model) as
+            # raw 2-byte records; the manifest names their dtype
+            arr = arr.view(jnp.dtype(entry["dtype"]))
+        flat[name] = arr
     return flat, manifest.get("meta", {})
 
 
@@ -601,7 +611,7 @@ def ensure_col_sums(params):
                     q = unpack_int4(node["packed"])
                 col = jnp.sum(
                     q.astype(jnp.int32), axis=-2, keepdims=True,
-                )
+                )[..., :node["scale"].shape[-1]]  # drop the lane padding
                 return {**node, "col_sums": col}
             return {k: fix(v) for k, v in node.items()}
         if isinstance(node, (list, tuple)):
@@ -678,7 +688,8 @@ def packed_weight_bytes(cfg: ModelConfig, *, scale_bytes_per: int = 2,
     At int4 the total weight stream matches dense (codes halve, metadata
     takes the other half) — the compressed layout's win is the halved
     *effective accumulation depth* (docs/datapath.md), not bytes.
-    Ineligible sites are counted dense."""
+    Ineligible sites are counted dense. Dense codes are counted at their
+    served size, zero-padded to whole 128-lane K and N blocks."""
     elems = code = scale = col = spec_b = act = bias = meta_b = 0
     for slot in packable_sites(cfg):
         for kind in ("mixer", "ffn"):
@@ -689,7 +700,9 @@ def packed_weight_bytes(cfg: ModelConfig, *, scale_bytes_per: int = 2,
                     code += s.k * s.c * st // 4  # 2 kept codes per group
                     meta_b += s.k * s.c * st // 4  # int8 index pair per group
                 else:
-                    code += s.k * s.c * st // 2  # int8 byte holds 2 codes
+                    # int8 byte holds 2 codes; K and N padded to whole lanes
+                    code += (_round_up(s.k, LANE) * _round_up(s.c, LANE)
+                             * st // 2)
                 scale += s.c * st * scale_bytes_per
                 col += s.c * st * 4  # int32
                 spec_b += st * _SPEC_ARR_LEN * 4  # f32 spec_arr twin

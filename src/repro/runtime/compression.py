@@ -20,30 +20,6 @@ import jax.numpy as jnp
 
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, manual_axes: frozenset):
-    """Partial-manual shard_map across jax API generations: ``jax.shard_map``
-    (axis_names = the manual set, check_vma) on new jax, the experimental
-    ``shard_map`` (auto = the complement, check_rep) on older releases."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False, axis_names=manual_axes,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    from .sharding import declared_manual_axes
-
-    def f_marked(*args):
-        # old jax's abstract mesh carries no AxisType: declare the manual
-        # axes explicitly so logical constraints inside the region drop them
-        with declared_manual_axes(manual_axes):
-            return f(*args)
-
-    return _shard_map(
-        f_marked, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=False, auto=frozenset(mesh.axis_names) - manual_axes,
-    )
-
 def int8_psum(tree, axis_name: str):
     """Compressed psum of a pytree over ``axis_name`` (inside shard_map)."""
 
@@ -80,12 +56,13 @@ def compressed_grad_fn(grad_fn, mesh, batch_spec_fn):
 
     def wrapped(params, batch):
         batch_specs = jax.tree.map(lambda _: P("pod"), batch)
-        return shard_map_compat(
+        return jax.shard_map(
             inner,
-            mesh,
-            (jax.tree.map(lambda _: P(), params), batch_specs),
-            P(),
-            frozenset({"pod"}),
+            mesh=mesh,
+            in_specs=(jax.tree.map(lambda _: P(), params), batch_specs),
+            out_specs=P(),
+            check_vma=False,
+            axis_names=frozenset({"pod"}),
         )(params, batch)
 
     return wrapped

@@ -68,41 +68,16 @@ def axis_rules(mesh: Mesh, rules: dict[str, tuple[str, ...]] | None = None):
         _state.active = prev
 
 
-def set_mesh(mesh: Mesh):
-    """Context manager activating ``mesh`` for jit/lower: ``jax.set_mesh``
-    on new jax, the Mesh's own context manager on older releases."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
-
-
-@contextmanager
-def declared_manual_axes(axes: frozenset):
-    """Explicitly mark mesh axes as manual for the enclosed trace — the
-    fallback for jax releases whose abstract mesh carries no AxisType
-    (see runtime.compression.shard_map_compat)."""
-    prev = getattr(_state, "manual", frozenset())
-    _state.manual = prev | axes
-    try:
-        yield
-    finally:
-        _state.manual = prev
-
-
 def _manual_axes() -> frozenset:
     """Mesh axes currently under manual (shard_map) control — they must not
     appear in sharding constraints issued from inside the region."""
-    declared = getattr(_state, "manual", frozenset())
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is None or am.empty:
-            return declared
-        return declared | frozenset(
-            n for n in am.axis_names
-            if am._name_to_type[n] == jax.sharding.AxisType.Manual
-        )
-    except Exception:
-        return declared
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty:
+        return frozenset()
+    return frozenset(
+        n for n in am.axis_names
+        if am._name_to_type[n] == jax.sharding.AxisType.Manual
+    )
 
 
 def _mesh_axes_for(logical: str | None, mesh: Mesh, rules: dict) -> tuple[str, ...]:

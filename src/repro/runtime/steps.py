@@ -30,7 +30,6 @@ from .sharding import (
     cache_shardings,
     param_shardings,
     replicated,
-    set_mesh,
 )
 
 
@@ -154,7 +153,7 @@ def lower_train_step(
         out_shardings=(state_sh, replicated(mesh)),
         donate_argnums=(0,),
     )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(state, batch_spec)
     return jitted, lowered, (state, state_sh, batch_sh)
 
@@ -186,7 +185,7 @@ def lower_prefill_step(cfg: ModelConfig, mesh: Mesh, batch_spec: dict, max_len: 
     batch_sh = batch_shardings(batch_spec, mesh)
     step = make_prefill_step(cfg, max_len, mesh)
     jitted = jax.jit(step, in_shardings=(params_sh, batch_sh))
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(params, batch_spec)
     return jitted, lowered, (params, params_sh)
 
@@ -224,7 +223,7 @@ def lower_decode_step(
         out_shardings=(logits_sh, cache_sh),
         donate_argnums=(2,),
     )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jitted.lower(params, tokens_spec, cache_spec, index_spec)
     return jitted, lowered, (params, params_sh, cache_sh)
 

@@ -50,6 +50,7 @@ from dataclasses import dataclass
 from functools import partial
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 
@@ -1346,10 +1347,10 @@ class PagedEngine:
                 for v in eqn.params.values():
                     for sub in jax.tree.leaves(
                             v, is_leaf=lambda x: isinstance(
-                                x, (jax.core.Jaxpr, jax.core.ClosedJaxpr))):
-                        if isinstance(sub, jax.core.ClosedJaxpr):
+                                x, (jex_core.Jaxpr, jex_core.ClosedJaxpr))):
+                        if isinstance(sub, jex_core.ClosedJaxpr):
                             walk(sub.jaxpr)
-                        elif isinstance(sub, jax.core.Jaxpr):
+                        elif isinstance(sub, jex_core.Jaxpr):
                             walk(sub)
 
         walk(closed.jaxpr)

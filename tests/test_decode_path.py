@@ -46,10 +46,13 @@ def test_packed_artifact_col_sums_matches_codes(rng):
     leaf = _pack_leaf(w)
     assert leaf["col_sums"].dtype == jnp.int32
     assert leaf["col_sums"].shape == (1, 24)
+    # the codes are stored padded to whole 128-lane blocks with zero codes
+    assert leaf["packed"].shape == (64, 128)
     expect = jnp.sum(unpack_int4(leaf["packed"]).astype(jnp.int32), axis=-2)
     np.testing.assert_array_equal(
-        np.asarray(leaf["col_sums"][0]), np.asarray(expect)
+        np.asarray(leaf["col_sums"][0]), np.asarray(expect[:24])
     )
+    assert not np.asarray(expect[24:]).any()
 
 
 def test_packed_linear_legacy_artifact_without_col_sums(rng):

@@ -58,9 +58,8 @@ def test_compressed_training_step_runs():
         with axis_rules(mesh):
             return step(state, batch)
 
-    from repro.runtime.sharding import set_mesh
 
     batch = {"tokens": jax.random.randint(jax.random.key(1), (4, 16), 0, 64)}
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         new_state, metrics = jax.jit(wrapped)(state, batch)
     assert np.isfinite(float(metrics["loss"]))

@@ -103,12 +103,15 @@ def test_dense_ppl_matches_pre_refactor_pipeline(setup):
     cfg, params, calib, evalb = setup
     qm = calibrate_and_quantize(params, cfg, calib, PTQConfig())
     assert qm.certified
-    # rtol accommodates cross-jax/BLAS reduction-order drift while still
-    # catching any semantic change in the recipe
+    # rtol accommodates BLAS reduction-order drift while still catching any
+    # semantic change in the recipe. Pinned on jax 0.9.0: its
+    # jax_threefry_partitionable default (True since jax 0.5) draws other
+    # init weights from the same key — with the flag off the float ppl
+    # reproduces the original 818.2585 pin.
     np.testing.assert_allclose(float_ppl(params, cfg, evalb),
-                               818.2583482083, rtol=1e-4)
+                               826.5824743693184, rtol=1e-4)
     np.testing.assert_allclose(quantized_ppl(qm, evalb),
-                               813.0594335265, rtol=1e-4)
+                               860.8623077379249, rtol=1e-4)
     np.testing.assert_allclose(qm.cert_summary()["min_headroom_bits"],
                                0.005602534910700285, rtol=1e-3)
 
