@@ -25,6 +25,11 @@ prefill, watermark reservation with preempt-and-requeue) — token streams
 stay bit-identical to the default FIFO loop. See
 docs/serving_scheduler.md.
 
+``--profile DIR`` records the timed generation with ``jax.profiler`` into
+DIR (the paged engine's ``serve/*`` host spans beside the device ops; see
+docs/serving.md, "Tracing and counters"). After the tokens/s line the
+paged engine prints what its counters saw over that generation.
+
 ``--mesh dp,tp`` serves the paged engine SPMD over a (data, model) mesh —
 kv-head-sharded pools, replicated admin leaves, fully-replicated host
 reads; token streams are bit-identical to the single-device engine. Under
@@ -54,6 +59,7 @@ from repro.quant.serve_packed import (
 )
 from repro.quant.spec import tree_datapath_fingerprint
 from repro.serving import GenerationEngine, PagedConfig, PagedEngine, SamplerConfig
+from repro.serving.metrics import ServeCounters
 
 
 def main(argv=None):
@@ -129,6 +135,10 @@ def main(argv=None):
                          "count, or 'auto' for all devices data-parallel. "
                          "Pools shard kv_heads, admin leaves replicate "
                          "(docs/multihost.md)")
+    ap.add_argument("--profile", type=str, default=None, metavar="DIR",
+                    help="trace the timed generation with jax.profiler "
+                         "into DIR (open it with TensorBoard's profile "
+                         "plugin or xprof)")
     ap.add_argument("--observe", action="store_true",
                     help="attach serving saturation counters (--paged): "
                          "static-quantizer clip counts + per-site/per-head "
@@ -269,15 +279,26 @@ def main(argv=None):
         if args.packed_backend != "auto"
         else contextlib.nullcontext()
     )
+    profile = (jax.profiler.trace(args.profile) if args.profile
+               else contextlib.nullcontext())
     with backend_ctx:
         gen(prompts, args.max_new)  # warm the jit bucket outside the timed region
-        t0 = time.time()
-        out = gen(prompts, args.max_new)
-        dt = time.time() - t0
+        if args.paged:
+            before = engine.counters.snapshot()
+        with profile:
+            t0 = time.time()
+            out = gen(prompts, args.max_new)
+            dt = time.time() - t0
     n_new = out.shape[1] - prompts.shape[1]
     loop = "paged" if args.paged else ("host-loop" if args.host_loop else "fused")
     print(f"[serve] batch={args.batch} new_tokens={n_new} {loop} "
           f"{dt:.2f}s  {args.batch * n_new / dt:.1f} tok/s")
+    if args.paged:
+        after = engine.counters.snapshot()
+        print("[serve] counters:", ServeCounters.summary(
+            {k: after[k] - before[k] for k in after}))
+    if args.profile:
+        print(f"[serve] trace written under {args.profile}")
     print("[serve] sample:", out[0, -min(16, out.shape[1]):].tolist())
     if args.observe:
         import json as _json

@@ -100,11 +100,14 @@ def attach_observer(obs):
 @contextmanager
 def site_scope(label: str):
     """Name the current component ("slot0/mixer") so packed sites report
-    under slot-granular labels matching the mixed-precision plan keys."""
+    under slot-granular labels matching the mixed-precision plan keys. The
+    label is also a ``jax.named_scope``: the component's ops carry it in
+    their name stack, and so in a profiler trace."""
     prev = getattr(_observe_state, "scope", None)
     _observe_state.scope = label
     try:
-        yield
+        with jax.named_scope(label):
+            yield
     finally:
         _observe_state.scope = prev
 
@@ -659,29 +662,30 @@ def paged_attention_decode(params, x, cfg: ModelConfig, pool,
         validate_attn_datapath(derived, attn_spec)
     page = jnp.where(active, block_table[jnp.arange(B), seq_lens // bs], nb)
     off = seq_lens % bs
-    if quantized:
-        if static_kv_scales is not None:
-            k_pages, k_scales = _append_kv_page_static(
-                k_pages, pool["k_scales"], page, off, k[:, 0],
-                static_kv_scales["k"])
-            v_pages, v_scales = _append_kv_page_static(
-                v_pages, pool["v_scales"], page, off, v[:, 0],
-                static_kv_scales["v"])
+    with jax.named_scope("kv_append"):
+        if quantized:
+            if static_kv_scales is not None:
+                k_pages, k_scales = _append_kv_page_static(
+                    k_pages, pool["k_scales"], page, off, k[:, 0],
+                    static_kv_scales["k"])
+                v_pages, v_scales = _append_kv_page_static(
+                    v_pages, pool["v_scales"], page, off, v[:, 0],
+                    static_kv_scales["v"])
+            else:
+                k_pages, k_scales = _append_kv_page_quant(
+                    k_pages, pool["k_scales"], page, off, k[:, 0])
+                v_pages, v_scales = _append_kv_page_quant(
+                    v_pages, pool["v_scales"], page, off, v[:, 0])
+            new_pool = {"k_pages": k_pages, "v_pages": v_pages,
+                        "k_scales": k_scales, "v_scales": v_scales}
+            scale_kw = {"k_scales": k_scales, "v_scales": v_scales}
         else:
-            k_pages, k_scales = _append_kv_page_quant(
-                k_pages, pool["k_scales"], page, off, k[:, 0])
-            v_pages, v_scales = _append_kv_page_quant(
-                v_pages, pool["v_scales"], page, off, v[:, 0])
-        new_pool = {"k_pages": k_pages, "v_pages": v_pages,
-                    "k_scales": k_scales, "v_scales": v_scales}
-        scale_kw = {"k_scales": k_scales, "v_scales": v_scales}
-    else:
-        k_pages = k_pages.at[page, off].set(k[:, 0].astype(k_pages.dtype),
-                                            mode="drop")
-        v_pages = v_pages.at[page, off].set(v[:, 0].astype(v_pages.dtype),
-                                            mode="drop")
-        new_pool = {"k_pages": k_pages, "v_pages": v_pages}
-        scale_kw = {}
+            k_pages = k_pages.at[page, off].set(
+                k[:, 0].astype(k_pages.dtype), mode="drop")
+            v_pages = v_pages.at[page, off].set(
+                v[:, 0].astype(v_pages.dtype), mode="drop")
+            new_pool = {"k_pages": k_pages, "v_pages": v_pages}
+            scale_kw = {}
     lens_now = seq_lens + 1  # attend over positions < lens_now (self incl.)
     if impl == "ref":
         out = paged_attention_reference(

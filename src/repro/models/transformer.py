@@ -294,6 +294,17 @@ def init_paged_cache(cfg: ModelConfig, num_slots: int, num_blocks: int,
     }
 
 
+def _named_layer(body):
+    """A layer scan's body under ``jax.named_scope("layer")``. The scan's
+    own work (slicing the stacked weights and pools per layer, writing the
+    stacked outputs, the carry) stays outside it, under the caller's
+    ``layer_scan`` scope: a profiler trace then tells the two apart."""
+    def named(carry, xs):
+        with jax.named_scope("layer"):
+            return body(carry, xs)
+    return named
+
+
 def decode_step_paged(params, tokens, cache, cfg: ModelConfig, *,
                       attn_impl: str = "ref", attn_spec=None,
                       kv_scales=None):
@@ -362,9 +373,11 @@ def decode_step_paged(params, tokens, cache, cfg: ModelConfig, *,
     xs = (params["layers"], cache["pools"])
     if kv_scales is not None:
         xs = (*xs, tuple(kv_scales))
-    x, pools = jax.lax.scan(body, x, xs)
-    x = norm(params["final_norm"], x, cfg.norm)
-    logits = lm_logits(params["embedding"], x, cfg)
+    with jax.named_scope("layer_scan"):
+        x, pools = jax.lax.scan(_named_layer(body), x, xs)
+    with jax.named_scope("head"):
+        x = norm(params["final_norm"], x, cfg.norm)
+        logits = lm_logits(params["embedding"], x, cfg)
     new_cache = dict(cache)
     new_cache["pools"] = pools
     new_cache["seq_lens"] = lens + active.astype(lens.dtype)
@@ -511,6 +524,8 @@ def prefill(params, batch, cfg: ModelConfig, max_len: int,
 
     xs = params["layers"] if prefix_kv is None else (params["layers"],
                                                      tuple(prefix_kv))
-    x, cache = jax.lax.scan(body, x, xs)
-    x = norm(params["final_norm"], x, cfg.norm)
-    return lm_logits(params["embedding"], x, cfg), cache
+    with jax.named_scope("layer_scan"):
+        x, cache = jax.lax.scan(_named_layer(body), x, xs)
+    with jax.named_scope("head"):
+        x = norm(params["final_norm"], x, cfg.norm)
+        return lm_logits(params["embedding"], x, cfg), cache

@@ -53,6 +53,7 @@ import jax
 import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.models.config import ModelConfig
 from repro.models.layers import (
@@ -76,7 +77,21 @@ from repro.quant.spec import (
     validate_datapath,
 )
 from repro.serving.engine import SamplerConfig, _sample
-from repro.serving.metrics import ServeMetrics
+from repro.serving.metrics import (
+    SPAN_ADMIT,
+    SPAN_DECODE,
+    SPAN_GROW,
+    SPAN_LATE,
+    SPAN_PASS,
+    SPAN_PLAN,
+    SPAN_PREFILL,
+    SPAN_READBACK,
+    SPAN_RECORD,
+    SPAN_RELEASE,
+    ServeCounters,
+    ServeMetrics,
+    pages_filled,
+)
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.scheduler import (
     PoolState,
@@ -296,8 +311,8 @@ class PagedEngine:
         self.stub_traces = 0
         self.prefill_chunk_traces = 0
         self.grow_traces = 0
-        #: host-observed preemption count across serve() calls
-        self.preemptions = 0
+        #: what the serve loops have done across serve() calls
+        self.counters = ServeCounters()
         self._uid_gen = 0
 
         def _osh(*out):
@@ -685,8 +700,9 @@ class PagedEngine:
                 params, cache["last_tok"][:, None], cache, cfg,
                 attn_impl=attn_impl, attn_spec=attn_spec,
                 kv_scales=self._kv_scales_seq)
-            keys = _fold_keys(samp.seed, cache["uids"], cache["steps"])
-            nxt = _sample_rows(logits[:, -1], samp.temperature, keys)
+            with jax.named_scope("sample"):
+                keys = _fold_keys(samp.seed, cache["uids"], cache["steps"])
+                nxt = _sample_rows(logits[:, -1], samp.temperature, keys)
             active = cache["active"]
             cache = dict(cache)
             cache["last_tok"] = jnp.where(active, nxt, cache["last_tok"])
@@ -886,6 +902,45 @@ class PagedEngine:
     # ------------------------------------------------------------------
     # Host loop
     # ------------------------------------------------------------------
+    @property
+    def preemptions(self) -> int:
+        """Preemptions across serve() calls."""
+        return self.counters.preemptions
+
+    def _read(self, x) -> np.ndarray:
+        """The serve loop's blocking read of device results, timed into
+        ``counters.readback_s``."""
+        t = time.perf_counter()
+        with TraceAnnotation(SPAN_READBACK):
+            out = np.asarray(shardlib.host_read(x))
+        self.counters.readback_s += time.perf_counter() - t
+        return out
+
+    def _release_pages(self, slot: int, pages) -> None:
+        """Drop one reader from each of a slot's ``pages`` (its row; or
+        evicted cache pages, with ``slot = max_concurrency``)."""
+        with TraceAnnotation(SPAN_RELEASE):
+            self.cache = self._release(self.cache, np.int32(slot),
+                                       self._pad_row(pages),
+                                       np.int32(len(pages)))
+
+    def _decode(self, sched, slots, k: int, backend, attn_impl) -> np.ndarray:
+        """Dispatch one ``k``-step decode chunk over the decoding ``slots``
+        and read its tokens back (the chunk's one host sync)."""
+        c, pc = self.counters, self.paged
+        st = sched.active
+        lens = [st[s].req.prompt.size + st[s].produced - 1 for s in slots]
+        c.decode_steps += k
+        c.attn_pages_live += pages_filled(lens, k, pc.block_size)
+        c.attn_pages_grid += k * pc.max_concurrency * pc.max_pages_per_seq
+        with TraceAnnotation(SPAN_DECODE):
+            self.cache, buf = self._chunk(
+                self.params, self.cache, np.int32(k), backend, attn_impl,
+                self.datapath_fingerprint, self.attn_spec)
+        # buf is fully replicated by the out_shardings contract, so this
+        # read is local on every process (docs/multihost.md)
+        return self._read(buf)
+
     def _make_scheduler(self) -> Scheduler:
         paged = self.paged
         return Scheduler(paged.max_concurrency, paged.num_blocks,
@@ -914,10 +969,7 @@ class PagedEngine:
         token, or None for a fully cached prompt — its first sample is
         deferred to the next decode chunk."""
         if adm.evict_pages is not None and adm.evict_pages.size:
-            self.cache = self._release(
-                self.cache, np.int32(self.paged.max_concurrency),
-                self._pad_row(adm.evict_pages),
-                np.int32(adm.evict_pages.size))
+            self._release_pages(self.paged.max_concurrency, adm.evict_pages)
         req = adm.req
         incs = np.asarray(adm.incs, np.int32)
         if adm.chunked:
@@ -937,6 +989,7 @@ class PagedEngine:
             return None
         if adm.n_shared:
             suffix = req.prompt[adm.n_shared * self.paged.block_size:]
+            self.counters.prefill_tokens += suffix.size
             self.cache, tok0 = self._admit_suffix(
                 self.params, self.cache,
                 np.asarray(suffix, np.int32)[None],
@@ -944,12 +997,13 @@ class PagedEngine:
                 adm.n_pages, adm.n_shared, backend, attn_impl,
                 self.datapath_fingerprint)
         else:
+            self.counters.prefill_tokens += req.prompt.size
             self.cache, tok0 = self._admit(
                 self.params, self.cache,
                 np.asarray(req.prompt, np.int32)[None], np.int32(adm.slot),
                 np.int32(req.uid), incs, adm.n_pages, backend, attn_impl,
                 self.datapath_fingerprint)
-        return int(shardlib.host_read(tok0))
+        return int(self._read(tok0))
 
     def _do_admit_batch(self, group, backend, attn_impl) -> np.ndarray:
         """Run one batched-admission group (>= 2 cold requests) through a
@@ -980,12 +1034,13 @@ class PagedEngine:
             # vectors stay replicated host inputs
             tokens = shardlib.host_to_global(
                 tokens, shardlib.rows_sharding(tokens.shape, self.mesh))
+        self.counters.prefill_tokens += int(s0s.sum())
         self.cache, toks = self._admit_batch(
             self.params, self.cache, tokens, s0s,
             slots, uids, rows,
             scat, incs, np.int32(total_pop),
             n, P, backend, attn_impl, self.datapath_fingerprint)
-        return np.asarray(shardlib.host_read(toks))
+        return self._read(toks)
 
     def _do_prefill_chunk(self, slot, sched, backend, attn_impl):
         """Advance one stub-admitted slot by one page-aligned prefill
@@ -994,6 +1049,7 @@ class PagedEngine:
         tokens, n_prior, final, incs = sched.take_prefill_chunk(slot)
         st = sched.active[slot]
         n_chunk_pages = -(-tokens.size // self.paged.block_size)
+        self.counters.prefill_tokens += tokens.size
         self.cache, tok0 = self._prefill_chunk(
             self.params, self.cache, np.asarray(tokens, np.int32)[None],
             np.int32(slot), np.int32(st.req.uid),
@@ -1001,7 +1057,7 @@ class PagedEngine:
             n_prior, n_chunk_pages, final, backend, attn_impl,
             self.datapath_fingerprint)
         if final:
-            return int(shardlib.host_read(tok0))
+            return int(self._read(tok0))
         return None
 
     @staticmethod
@@ -1080,6 +1136,7 @@ class PagedEngine:
         backend = packed_backend()
         attn_impl = resolve_paged_attn_impl(self.paged.attn_impl)
         eos = self.sampler.eos_id
+        counters = self.counters
         results: dict[int, np.ndarray] = {}
         chunk_idx = 0
         t0 = time.perf_counter()
@@ -1096,14 +1153,13 @@ class PagedEngine:
 
         def note(slot, toks):
             sched.record(slot, toks)
+            counters.tokens_out += len(toks)
             if metrics is not None and toks:
                 metrics.tokens(sched.active[slot].req.uid, len(toks), now())
 
         def finish(slot):
             st = sched.finish(slot)
-            self.cache = self._release(self.cache, np.int32(slot),
-                                       self._pad_row(st.row),
-                                       np.int32(st.n_pages))
+            self._release_pages(slot, st.row)
             results[st.req.uid] = np.concatenate(
                 [st.req.prompt, np.asarray(st.tokens, np.int32)])
             if _probe is not None:
@@ -1115,40 +1171,45 @@ class PagedEngine:
                 if not sched.has_work:
                     time.sleep(max(0.0, pending[0][0] - now()))
                     continue
-            adm = sched.try_admit()
-            while adm is not None:
-                tok0 = self._do_admit(adm, backend, attn_impl)
-                if tok0 is not None:
-                    note(adm.slot, [tok0])
-                if _probe is not None:
-                    _probe(self, sched)
-                if tok0 is not None and (
-                        sched.remaining(adm.slot) == 0 or tok0 == eos):
-                    finish(adm.slot)
+            t_pass = time.perf_counter()
+            with TraceAnnotation(SPAN_PASS, index=chunk_idx,
+                                 **counters.snapshot()):
                 adm = sched.try_admit()
-            if sched.active:
-                k = min(self.paged.chunk_max, sched.min_remaining())
-                self.cache, buf = self._chunk(
-                    self.params, self.cache, np.int32(k), backend, attn_impl,
-                    self.datapath_fingerprint, self.attn_spec)
-                # the chunk's ONE host sync: buf is fully replicated by
-                # the out_shardings contract, so this read is local on
-                # every process (docs/multihost.md)
-                buf = np.asarray(shardlib.host_read(buf))
-                if _probe is not None:
-                    _probe(self, sched)
-                for slot in list(sched.active):
-                    toks = buf[slot, :k].tolist()[: sched.remaining(slot)]
-                    if eos is not None and eos in toks:
-                        toks = toks[: toks.index(eos) + 1]
-                    note(slot, toks)
-                    if sched.remaining(slot) == 0 or (
-                            eos is not None and toks and toks[-1] == eos):
-                        finish(slot)
-            elif sched.queue:  # cannot happen: submit() validates fit
-                raise RuntimeError("queued requests can never be admitted")
-            if _late is not None:
-                _late(sched, chunk_idx)
+                while adm is not None:
+                    with TraceAnnotation(SPAN_ADMIT, uid=adm.req.uid):
+                        tok0 = self._do_admit(adm, backend, attn_impl)
+                        if tok0 is not None:
+                            note(adm.slot, [tok0])
+                        if _probe is not None:
+                            _probe(self, sched)
+                        if tok0 is not None and (
+                                sched.remaining(adm.slot) == 0 or tok0 == eos):
+                            finish(adm.slot)
+                    adm = sched.try_admit()
+                if sched.active:
+                    k = min(self.paged.chunk_max, sched.min_remaining())
+                    buf = self._decode(sched, list(sched.active), k, backend,
+                                       attn_impl)
+                    if _probe is not None:
+                        _probe(self, sched)
+                    with TraceAnnotation(SPAN_RECORD):
+                        for slot in list(sched.active):
+                            toks = buf[slot, :k].tolist()[
+                                : sched.remaining(slot)]
+                            if eos is not None and eos in toks:
+                                toks = toks[: toks.index(eos) + 1]
+                            note(slot, toks)
+                            if sched.remaining(slot) == 0 or (
+                                    eos is not None and toks
+                                    and toks[-1] == eos):
+                                finish(slot)
+                elif sched.queue:  # cannot happen: submit() validates fit
+                    raise RuntimeError("queued requests can never be admitted")
+                if _late is not None:
+                    with TraceAnnotation(SPAN_LATE):
+                        _late(sched, chunk_idx)
+                counters.passes += 1
+                counters.pass_s += time.perf_counter() - t_pass
             chunk_idx += 1
         return results
 
@@ -1175,6 +1236,7 @@ class PagedEngine:
         backend = packed_backend()
         attn_impl = resolve_paged_attn_impl(self.paged.attn_impl)
         eos = self.sampler.eos_id
+        counters = self.counters
         results: dict[int, np.ndarray] = {}
         pass_idx = 0
         t0 = time.perf_counter()
@@ -1191,14 +1253,13 @@ class PagedEngine:
 
         def note(slot, toks):
             sched.record(slot, toks)
+            counters.tokens_out += len(toks)
             if metrics is not None and toks:
                 metrics.tokens(sched.active[slot].req.uid, len(toks), now())
 
         def finish(slot):
             st = sched.finish(slot)
-            self.cache = self._release(self.cache, np.int32(slot),
-                                       self._pad_row(st.row),
-                                       np.int32(st.n_pages))
+            self._release_pages(slot, st.row)
             results[st.req.uid] = np.concatenate(
                 [st.req.prompt, np.asarray(st.tokens, np.int32)])
             if _probe is not None:
@@ -1212,15 +1273,9 @@ class PagedEngine:
                     eos is not None and st.tokens and st.tokens[-1] == eos):
                 finish(slot)
 
-        while sched.has_work or pending:
-            if pending:
-                submit_due()
-                if not sched.has_work:
-                    time.sleep(max(0.0, pending[0][0] - now()))
-                    continue
-            progressed = False
-
-            def take_chunk(slot):
+        def take_chunk(slot):
+            with TraceAnnotation(SPAN_PREFILL,
+                                 uid=sched.active[slot].req.uid):
                 tok0 = self._do_prefill_chunk(slot, sched, backend, attn_impl)
                 if _probe is not None:
                     _probe(self, sched)
@@ -1228,94 +1283,112 @@ class PagedEngine:
                     note(slot, [tok0])
                     maybe_finish(slot)
 
-            # In-flight prefills advance *before* new admissions: a
-            # prefilling slot is older than anything still queued, and a
-            # burst of batched admits must not starve its next chunk (the
-            # final chunk is the request's first token).
-            chunked_first = sched.prefilling_slots()
-            for slot in chunked_first:
-                progressed = True
-                take_chunk(slot)
-            # ``admit_pass`` commits every group host-side up front; the
-            # device only catches up as each group's program runs, so
-            # probes and releases (a finish's device push must not
-            # interleave with this pass's remaining device pops — the
-            # free-list replay order is the lockstep contract) wait until
-            # the whole pass has executed.
-            admitted = []
-            for group in sched.admit_pass():
-                progressed = True
-                if len(group) == 1:
-                    adm = group[0]
-                    tok0 = self._do_admit(adm, backend, attn_impl)
-                    if tok0 is not None:
-                        note(adm.slot, [tok0])
-                else:
-                    toks = self._do_admit_batch(group, backend, attn_impl)
-                    for adm, t in zip(group, toks):
-                        note(adm.slot, [int(t)])
-                admitted.extend(group)
-            if admitted:
-                if _probe is not None:
-                    _probe(self, sched)
-                for adm in admitted:
-                    maybe_finish(adm.slot)
-            for slot in sched.prefilling_slots():
-                if slot in chunked_first:
-                    continue  # one chunk per slot per pass
-                progressed = True
-                take_chunk(slot)
-            plan = sched.plan_chunk(self.paged.chunk_max)
-            if plan is not None:
-                for v in plan.victims:
-                    progressed = True  # freed pages: replanned next pass
-                    st = sched.preempt(v)
-                    self.preemptions += 1
-                    self.cache = self._release(self.cache, np.int32(v),
-                                               self._pad_row(st.row),
-                                               np.int32(st.n_pages))
-                    if metrics is not None:
-                        metrics.preempted(st.req.uid)
-                    if _probe is not None:
-                        _probe(self, sched)
-                if plan.evict_nodes:
-                    pages = sched._commit_evict(plan.evict_nodes)
-                    self.cache = self._release(
-                        self.cache, np.int32(self.paged.max_concurrency),
-                        self._pad_row(pages), np.int32(pages.size))
-                    if _probe is not None:
-                        _probe(self, sched)
-                for slot, n_new in plan.grow:
-                    pages, held = sched.commit_grow(slot, n_new)
-                    add = np.zeros(self.paged.max_pages_per_seq, np.int32)
-                    add[held:held + n_new] = 1
-                    self.cache = self._grow(
-                        self.cache, np.int32(slot),
-                        self._pad_row(sched.active[slot].row),
-                        add, np.int32(n_new))
-                    if _probe is not None:
-                        _probe(self, sched)
-                if plan.slots:
+        while sched.has_work or pending:
+            if pending:
+                submit_due()
+                if not sched.has_work:
+                    time.sleep(max(0.0, pending[0][0] - now()))
+                    continue
+            t_pass = time.perf_counter()
+            with TraceAnnotation(SPAN_PASS, index=pass_idx,
+                                 **counters.snapshot()):
+                progressed = False
+                # In-flight prefills advance *before* new admissions: a
+                # prefilling slot is older than anything still queued, and
+                # a burst of batched admits must not starve its next chunk
+                # (the final chunk is the request's first token).
+                chunked_first = sched.prefilling_slots()
+                for slot in chunked_first:
                     progressed = True
-                    self.cache, buf = self._chunk(
-                        self.params, self.cache, np.int32(plan.k), backend,
-                        attn_impl, self.datapath_fingerprint, self.attn_spec)
-                    buf = np.asarray(shardlib.host_read(buf))
+                    take_chunk(slot)
+                # ``admit_pass`` commits every group host-side up front;
+                # the device only catches up as each group's program runs,
+                # so probes and releases (a finish's device push must not
+                # interleave with this pass's remaining device pops — the
+                # free-list replay order is the lockstep contract) wait
+                # until the whole pass has executed.
+                admitted = []
+                for group in sched.admit_pass():
+                    progressed = True
+                    if len(group) == 1:
+                        adm = group[0]
+                        with TraceAnnotation(SPAN_ADMIT, uid=adm.req.uid):
+                            tok0 = self._do_admit(adm, backend, attn_impl)
+                        if tok0 is not None:
+                            note(adm.slot, [tok0])
+                    else:
+                        uids = ",".join(str(a.req.uid) for a in group)
+                        with TraceAnnotation(SPAN_ADMIT, uids=uids):
+                            toks = self._do_admit_batch(group, backend,
+                                                        attn_impl)
+                        for adm, t in zip(group, toks):
+                            note(adm.slot, [int(t)])
+                    admitted.extend(group)
+                if admitted:
+                    if _probe is not None:
+                        _probe(self, sched)
+                    for adm in admitted:
+                        maybe_finish(adm.slot)
+                for slot in sched.prefilling_slots():
+                    if slot in chunked_first:
+                        continue  # one chunk per slot per pass
+                    progressed = True
+                    take_chunk(slot)
+                with TraceAnnotation(SPAN_PLAN):
+                    plan = sched.plan_chunk(self.paged.chunk_max)
+                    if plan is not None:
+                        for v in plan.victims:
+                            progressed = True  # freed pages: replanned
+                            st = sched.preempt(v)
+                            counters.preemptions += 1
+                            self._release_pages(v, st.row)
+                            if metrics is not None:
+                                metrics.preempted(st.req.uid)
+                            if _probe is not None:
+                                _probe(self, sched)
+                        if plan.evict_nodes:
+                            self._release_pages(
+                                self.paged.max_concurrency,
+                                sched._commit_evict(plan.evict_nodes))
+                            if _probe is not None:
+                                _probe(self, sched)
+                if plan is not None and plan.grow:
+                    with TraceAnnotation(SPAN_GROW):
+                        for slot, n_new in plan.grow:
+                            pages, held = sched.commit_grow(slot, n_new)
+                            add = np.zeros(self.paged.max_pages_per_seq,
+                                           np.int32)
+                            add[held:held + n_new] = 1
+                            self.cache = self._grow(
+                                self.cache, np.int32(slot),
+                                self._pad_row(sched.active[slot].row),
+                                add, np.int32(n_new))
+                            if _probe is not None:
+                                _probe(self, sched)
+                if plan is not None and plan.slots:
+                    progressed = True
+                    buf = self._decode(sched, plan.slots, plan.k, backend,
+                                       attn_impl)
                     sched.advance_decode(plan.k)
                     if _probe is not None:
                         _probe(self, sched)
-                    for slot in plan.slots:
-                        toks = buf[slot, :plan.k].tolist()[
-                            : sched.remaining(slot)]
-                        if eos is not None and eos in toks:
-                            toks = toks[: toks.index(eos) + 1]
-                        note(slot, toks)
-                        maybe_finish(slot)
-            if not progressed and not sched.active and sched.queue \
-                    and not pending:
-                raise RuntimeError("queued requests can never be admitted")
-            if _late is not None:
-                _late(sched, pass_idx)
+                    with TraceAnnotation(SPAN_RECORD):
+                        for slot in plan.slots:
+                            toks = buf[slot, :plan.k].tolist()[
+                                : sched.remaining(slot)]
+                            if eos is not None and eos in toks:
+                                toks = toks[: toks.index(eos) + 1]
+                            note(slot, toks)
+                            maybe_finish(slot)
+                if not progressed and not sched.active and sched.queue \
+                        and not pending:
+                    raise RuntimeError(
+                        "queued requests can never be admitted")
+                if _late is not None:
+                    with TraceAnnotation(SPAN_LATE):
+                        _late(sched, pass_idx)
+                counters.passes += 1
+                counters.pass_s += time.perf_counter() - t_pass
             pass_idx += 1
         return results
 
