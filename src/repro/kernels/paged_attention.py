@@ -17,15 +17,18 @@ the block table. Two implementations share one contract:
 * :func:`paged_decode_attention` — the Pallas kernel. Grid ``(B, P)``
   with the page axis sequential; the block table and sequence lengths
   ride in as *scalar prefetch* operands so each page's BlockSpec
-  index_map can dereference ``table[b, j]`` before the body runs — HBM
-  traffic per row is O(block-table width), not O(pool size). (Sentinel
-  entries past a row's length clamp to page ``nb-1`` and are fetched
-  then fully masked; skipping their DMA needs a per-row page-count grid
-  — part of the TPU-hardware validation follow-up in the ROADMAP.)
-  Scores accumulate via online
-  softmax (running max / normalizer / weighted accumulator in VMEM
-  scratch, exactly the ``_chunked_causal_attention`` recurrence), so
-  kernel-vs-reference agreement is to float tolerance, not bitwise.
+  index_map can dereference ``table[b, j]`` before the body runs. Work
+  and HBM traffic per row are O(live pages), not O(pool size) nor
+  O(table width): a grid step whose page lies wholly past the row's
+  length skips the body (the online-softmax update of a fully masked
+  page is the identity), and its index_map repeats the row's last live
+  page, a block the pipeline already holds. A dead table entry costs a
+  grid step's overhead, not a page's work, whatever it holds: a
+  sentinel, or a real page reserved for the row's worst case. Scores
+  accumulate via online softmax (running max / normalizer / weighted
+  accumulator in VMEM scratch, exactly the ``_chunked_causal_attention``
+  recurrence), so kernel-vs-reference agreement is to float tolerance,
+  not bitwise.
 
 Both implementations additionally serve **int8 quantized KV pages**
 (``kv_dtype=int8`` in ``transformer.init_paged_cache``): the pools hold
@@ -150,12 +153,30 @@ def paged_attention_reference(q, k_pages, v_pages, block_table, seq_lens, *,
     return out.reshape(B, nh, hd)
 
 
+def _live_entry(b, j, tab, lens, *, bs: int, nb: int):
+    """The pool page grid step ``(b, j)`` fetches: its own table entry
+    while that page holds live positions, and past the row's length the
+    row's last live page again: the block the pipeline already holds, so
+    a dead step has nothing new to fetch. Sentinels clamp to page
+    ``nb-1``; a length-0 row reads entry 0."""
+    last = jnp.maximum(lens[b] - 1, 0) // bs
+    return jnp.minimum(tab[b, jnp.minimum(j, last)], nb - 1)
+
+
 def _init_softmax_state(j, m_ref, l_ref, acc_ref):
     @pl.when(j == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _when_live(b, j, lens_ref, bs):
+    """Run the decorated page body only on grid steps whose page holds
+    positions below row ``b``'s length. Keyed on the length, never on the
+    table entry: under worst-case reservation the entries past it are
+    real pages."""
+    return pl.when(j * bs < lens_ref[b])
 
 
 def _mask_scores(s, j, b, lens_ref, bs, nh):
@@ -171,12 +192,16 @@ def _softmax_accumulate(s, m_ref, l_ref, acc_ref, pv_of):
     the page's probabilities (nh, bs) to (effective weights for the
     normalizer, PV numerator (nh, hd)) — the float body uses p itself,
     the int8 body its quantized codes, keeping numerator and denominator
-    consistent by construction."""
+    consistent by construction.
+
+    Only live pages run it (:func:`_when_live`), and a live page holds at
+    least one unmasked score, so ``m_new`` is finite: masked scores give
+    ``p = 0``, and the first page's ``corr = exp(-inf) = 0`` clears the
+    empty state."""
     m_prev = m_ref[...]  # (nh, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-    p = jnp.exp(s - m_safe)  # fully-masked rows: exp(-inf) = 0
-    corr = jnp.exp(jnp.where(jnp.isfinite(m_prev), m_prev - m_safe, -jnp.inf))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
     p_eff, pv = pv_of(p)
     l_ref[...] = l_ref[...] * corr + jnp.sum(p_eff, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * corr + pv
@@ -197,19 +222,22 @@ def _kernel(tab_ref, lens_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
     nh = nkv * g
     _init_softmax_state(j, m_ref, l_ref, acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)  # (nh, hd)
-    k = k_ref[0].astype(jnp.float32)  # (bs, nkv, hd)
-    v = v_ref[0].astype(jnp.float32)
-    qg = q.reshape(nkv, g, hd)
-    s = jnp.einsum("kgd,skd->kgs", qg, k).astype(jnp.float32)
-    s = _softcap(s / math.sqrt(hd), softcap)
-    s = _mask_scores(s, j, b, lens_ref, bs, nh)
+    @_when_live(b, j, lens_ref, bs)
+    def _page():
+        q = q_ref[0].astype(jnp.float32)  # (nh, hd)
+        k = k_ref[0].astype(jnp.float32)  # (bs, nkv, hd)
+        v = v_ref[0].astype(jnp.float32)
+        qg = q.reshape(nkv, g, hd)
+        s = jnp.einsum("kgd,skd->kgs", qg, k).astype(jnp.float32)
+        s = _softcap(s / math.sqrt(hd), softcap)
+        s = _mask_scores(s, j, b, lens_ref, bs, nh)
 
-    def pv_of(p):
-        pv = jnp.einsum("kgs,skd->kgd", p.reshape(nkv, g, bs), v)
-        return p, pv.reshape(nh, hd)
+        def pv_of(p):
+            pv = jnp.einsum("kgs,skd->kgd", p.reshape(nkv, g, bs), v)
+            return p, pv.reshape(nh, hd)
 
-    _softmax_accumulate(s, m_ref, l_ref, acc_ref, pv_of)
+        _softmax_accumulate(s, m_ref, l_ref, acc_ref, pv_of)
+
     _finalize_output(j, n_pages, o_ref, m_ref, l_ref, acc_ref, out_dtype)
 
 
@@ -240,46 +268,49 @@ def _quant_kernel(tab_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     nh = nkv * g
     _init_softmax_state(j, m_ref, l_ref, acc_ref)
 
-    q = q_ref[0].astype(jnp.float32)  # (nh, hd)
-    # per-head symmetric quantization of the query rows (the A-side codes)
-    q_amax = jnp.max(jnp.abs(q), axis=-1, keepdims=True)  # (nh, 1)
-    q_scale = jnp.maximum(q_amax / spec.q_qmax, 1e-8)
-    q_codes = jnp.clip(jnp.rint(q / q_scale), -spec.q_qmax, spec.q_qmax)
-    k_codes = k_ref[0].astype(jnp.float32)  # (bs, nkv, hd) int8 codes
-    k_scale = ks_ref[0]  # (nkv, 1) f32 — this page's per-head scale
+    @_when_live(b, j, lens_ref, bs)
+    def _page():
+        q = q_ref[0].astype(jnp.float32)  # (nh, hd)
+        # per-head symmetric quantization of the query rows (the A-side codes)
+        q_amax = jnp.max(jnp.abs(q), axis=-1, keepdims=True)  # (nh, 1)
+        q_scale = jnp.maximum(q_amax / spec.q_qmax, 1e-8)
+        q_codes = jnp.clip(jnp.rint(q / q_scale), -spec.q_qmax, spec.q_qmax)
+        k_codes = k_ref[0].astype(jnp.float32)  # (bs, nkv, hd) int8 codes
+        k_scale = ks_ref[0]  # (nkv, 1) f32 — this page's per-head scale
 
-    # hd-deep integer QK^T dot, held in the P_qk register
-    s_int = jnp.einsum("kgd,skd->kgs",
-                       q_codes.reshape(nkv, g, hd).astype(jnp.bfloat16),
-                       k_codes.astype(jnp.bfloat16),
-                       preferred_element_type=jnp.float32)
-    if assert_bounds:
-        _register_check(jnp.max(jnp.abs(s_int)), spec.p_qk, "QK^T")
-    s = (s_int * q_scale.reshape(nkv, g, 1)
-         * k_scale[:, :, None])
-    s = _softcap(s / math.sqrt(hd), softcap)
-    s = _mask_scores(s, j, b, lens_ref, bs, nh)
-
-    def pv_of(p):
-        # probability codes (unsigned prob_bits) — the PV A-side operand;
-        # the normalizer accumulates the *quantized* probabilities so the
-        # final weighted average stays consistent with the PV numerator
-        p_codes = jnp.rint(p * spec.prob_qmax)  # (nh, bs), 0..prob_qmax
-        v_codes = v_ref[0].astype(jnp.float32)  # (bs, nkv, hd)
-        v_scale = vs_ref[0]  # (nkv, 1)
-        # per-page block_size-deep integer PV dot, held in the P_pv
-        # register — the page is the tile; partials drain scaled into the
-        # f32 outer accumulator
-        pv_int = jnp.einsum("kgs,skd->kgd",
-                            p_codes.reshape(nkv, g, bs).astype(jnp.bfloat16),
-                            v_codes.astype(jnp.bfloat16),
-                            preferred_element_type=jnp.float32)
+        # hd-deep integer QK^T dot, held in the P_qk register
+        s_int = jnp.einsum("kgd,skd->kgs",
+                           q_codes.reshape(nkv, g, hd).astype(jnp.bfloat16),
+                           k_codes.astype(jnp.bfloat16),
+                           preferred_element_type=jnp.float32)
         if assert_bounds:
-            _register_check(jnp.max(jnp.abs(pv_int)), spec.p_pv, "PV")
-        pv = pv_int * (v_scale[:, :, None] / spec.prob_qmax)
-        return p_codes / spec.prob_qmax, pv.reshape(nh, hd)
+            _register_check(jnp.max(jnp.abs(s_int)), spec.p_qk, "QK^T")
+        s = (s_int * q_scale.reshape(nkv, g, 1)
+             * k_scale[:, :, None])
+        s = _softcap(s / math.sqrt(hd), softcap)
+        s = _mask_scores(s, j, b, lens_ref, bs, nh)
 
-    _softmax_accumulate(s, m_ref, l_ref, acc_ref, pv_of)
+        def pv_of(p):
+            # probability codes (unsigned prob_bits) — the PV A-side operand;
+            # the normalizer accumulates the *quantized* probabilities so the
+            # final weighted average stays consistent with the PV numerator
+            p_codes = jnp.rint(p * spec.prob_qmax)  # (nh, bs), 0..prob_qmax
+            v_codes = v_ref[0].astype(jnp.float32)  # (bs, nkv, hd)
+            v_scale = vs_ref[0]  # (nkv, 1)
+            # per-page block_size-deep integer PV dot, held in the P_pv
+            # register — the page is the tile; partials drain scaled into the
+            # f32 outer accumulator
+            pv_int = jnp.einsum("kgs,skd->kgd",
+                                p_codes.reshape(nkv, g, bs).astype(jnp.bfloat16),
+                                v_codes.astype(jnp.bfloat16),
+                                preferred_element_type=jnp.float32)
+            if assert_bounds:
+                _register_check(jnp.max(jnp.abs(pv_int)), spec.p_pv, "PV")
+            pv = pv_int * (v_scale[:, :, None] / spec.prob_qmax)
+            return p_codes / spec.prob_qmax, pv.reshape(nh, hd)
+
+        _softmax_accumulate(s, m_ref, l_ref, acc_ref, pv_of)
+
     _finalize_output(j, n_pages, o_ref, m_ref, l_ref, acc_ref, out_dtype)
 
 
@@ -293,7 +324,8 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens, *,
     """Paged decode attention as a Pallas kernel; same contract as
     :func:`paged_attention_reference`. The block table and lengths are
     scalar-prefetched so the K/V BlockSpec index_maps can walk
-    ``table[b, j]`` — only the sequence's own pages transit HBM->VMEM.
+    ``table[b, j]`` — only the row's own live pages transit HBM->VMEM,
+    and only they run the body.
 
     Passing ``k_scales``/``v_scales`` selects the int8 body, whose QK^T /
     PV registers are certified by an
@@ -315,8 +347,10 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens, *,
         # the same contract as validate_datapath on unpacked leaves
         validate_attn_datapath(None, attn_spec)
 
+    live_entry = functools.partial(_live_entry, bs=bs, nb=nb)
+
     def page_idx(b, j, tab, lens):
-        return (jnp.minimum(tab[b, j], nb - 1), 0, 0, 0)
+        return (live_entry(b, j, tab, lens), 0, 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, nh, hd), lambda b, j, tab, lens: (b, 0, 0)),
@@ -326,7 +360,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_table, seq_lens, *,
     operands = [block_table, seq_lens, q, k_pages, v_pages]
     if quantized:
         def scale_idx(b, j, tab, lens):
-            return (jnp.minimum(tab[b, j], nb - 1), 0, 0)
+            return (live_entry(b, j, tab, lens), 0, 0)
 
         # (nb, nkv, 1): the block's last two dims are the array's own (a
         # legal block), and the kernel reads the scales one head per sublane

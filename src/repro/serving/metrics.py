@@ -136,7 +136,8 @@ class ServeCounters:
 
     ``attn_pages_live`` / ``attn_pages_grid`` are per decode step (not per
     layer): the pages the decoding rows' live KV fills, against the pages
-    the attention kernel's (rows, table width) grid walks. ``pass_s`` is
+    the attention kernel's (rows, table width) grid walks; the kernel
+    computes the former and skips the rest. ``pass_s`` is
     the host time of the loop's passes, ``readback_s`` the part of it
     spent blocked on device results; what remains is host work in which
     the device waits."""

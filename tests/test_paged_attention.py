@@ -11,6 +11,7 @@ import numpy as np
 import pytest
 
 from repro.kernels.paged_attention import (
+    _live_entry,
     dequantize_kv_pages,
     paged_attention_reference,
     paged_decode_attention,
@@ -18,8 +19,12 @@ from repro.kernels.paged_attention import (
 )
 
 
-def _random_case(rng, B, nkv, g, hd, bs, P, extra_blocks=4, dtype=jnp.float32):
-    """Distinct pages per row, ragged lengths, sentinel tail entries."""
+def _random_case(rng, B, nkv, g, hd, bs, P, extra_blocks=4, dtype=jnp.float32,
+                 lens=None, tail="sentinel"):
+    """Distinct pages per row, ragged lengths (random unless given). Table
+    entries past a row's length are sentinels, or with ``tail="reserved"``
+    (worst-case reservation) real pages of the row's own. Page ``nb-1``,
+    where sentinels clamp, never holds live KV."""
     nb = B * P + extra_blocks
     nh = nkv * g
     q = jnp.asarray(rng.normal(size=(B, nh, hd)), dtype)
@@ -27,13 +32,22 @@ def _random_case(rng, B, nkv, g, hd, bs, P, extra_blocks=4, dtype=jnp.float32):
     vp = jnp.asarray(rng.normal(size=(nb, bs, nkv, hd)), dtype)
     tab = np.full((B, P), nb, np.int32)  # sentinel = nb
     perm = rng.permutation(nb)
-    lens = rng.integers(1, P * bs + 1, size=B).astype(np.int32)
+    perm = perm[perm != nb - 1]
+    if lens is None:
+        lens = rng.integers(1, P * bs + 1, size=B)
+    lens = np.asarray(lens, np.int32)
     o = 0
     for b in range(B):
-        n_pages = -(-int(lens[b]) // bs)
+        n_pages = P if tail == "reserved" else -(-int(lens[b]) // bs)
         tab[b, :n_pages] = perm[o:o + n_pages]
         o += n_pages
     return q, kp, vp, jnp.asarray(tab), jnp.asarray(lens)
+
+
+def _dead_entries(tab, lens, bs):
+    """(B, P) mask of the table entries wholly past each row's length."""
+    P = tab.shape[1]
+    return np.arange(P)[None, :] * bs >= np.asarray(lens)[:, None]
 
 
 def _quantize_case(kp, vp):
@@ -79,6 +93,25 @@ def test_kernel_exact_page_boundary_lengths(rng):
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("tail", ["sentinel", "reserved"])
+def test_dead_steps_fetch_the_last_live_page(rng, tail):
+    """The K/V index_map walks a row's live pages, then repeats the last:
+    one block per live page (one for an idle row) crosses HBM, and a dead
+    step asks for the block already resident — also under worst-case
+    reservation, where the entries past the length are real pages."""
+    B, bs, P = 4, 8, 4
+    lens = np.asarray([0, 1, 2 * bs, P * bs], np.int32)
+    _, kp, _, tab, lens = _random_case(rng, B, 1, 1, 8, bs, P, lens=lens,
+                                       tail=tail)
+    nb = kp.shape[0]
+    for b in range(B):
+        n_live = max(-(-int(lens[b]) // bs), 1)
+        walk = [int(_live_entry(b, j, tab, lens, bs=bs, nb=nb))
+                for j in range(P)]
+        live = np.minimum(np.asarray(tab)[b, :n_live], nb - 1).tolist()
+        assert walk == live + live[-1:] * (P - n_live)
+
+
 def test_reference_matches_dense_slab_math(rng):
     """The gather reference is bit-identical to the dense ``(B, S, nkv,
     hd)`` decode-attention math when pages are laid out contiguously —
@@ -113,7 +146,9 @@ def test_reference_matches_dense_slab_math(rng):
 # ---------------------------------------------------------------------------
 def _sweep_lens(rng, B, bs, P, mode):
     """Row lengths exercising the mode: not-divisible, exact last block,
-    and the ±1 brackets around an exact last block."""
+    the ±1 brackets around an exact last block; "idle" puts a length-0
+    row beside the full table, an exactly full last page and one past it,
+    and "reserved" serves the same lengths under worst-case reservation."""
     full = P * bs
     if mode == "ragged":  # S % bs != 0 everywhere
         lens = [(i * bs + 1 + int(rng.integers(0, bs - 1))) % full or 1
@@ -121,18 +156,23 @@ def _sweep_lens(rng, B, bs, P, mode):
         lens = [ln if ln % bs else ln - 1 or 1 for ln in lens]
     elif mode == "exact":  # every row ends exactly on a page boundary
         lens = [((i % P) + 1) * bs for i in range(B)]
-    else:  # "exact±1": brackets around the boundary (and the full table)
+    elif mode == "exact±1":  # brackets around the boundary (and full table)
         lens = [max(1, bs - 1), bs + 1, full, max(1, full - 1)][:B]
-    return jnp.asarray(lens, jnp.int32)
+    else:  # "idle" / "reserved": an idle slot among live rows
+        lens = [0, full, bs, bs + 1][:B]
+    return np.asarray(lens, np.int32)
 
 
 @pytest.mark.parametrize("kv", ["float", "int8"])
 @pytest.mark.parametrize("bs", [8, 16, 128])
-@pytest.mark.parametrize("mode", ["ragged", "exact", "exact±1"])
+@pytest.mark.parametrize("mode",
+                         ["ragged", "exact", "exact±1", "idle", "reserved"])
 def test_kernel_parity_sweep_block_sizes(rng, kv, bs, mode):
     B, nkv, g, hd, P = 4, 2, 2, 16, 2 if bs == 128 else 3
-    q, kp, vp, tab, _ = _random_case(rng, B, nkv, g, hd, bs, P)
     lens = _sweep_lens(rng, B, bs, P, mode)
+    tail = "reserved" if mode == "reserved" else "sentinel"
+    q, kp, vp, tab, lens = _random_case(rng, B, nkv, g, hd, bs, P,
+                                        lens=lens, tail=tail)
     if kv == "float":
         ref = paged_attention_reference(q, kp, vp, tab, lens)
         ker = paged_decode_attention(q, kp, vp, tab, lens, interpret=True)
@@ -146,7 +186,56 @@ def test_kernel_parity_sweep_block_sizes(rng, kv, bs, mode):
         # quantized on top of the shared KV codes); the reference dequantizes
         # and runs float math — agreement is to quantization tolerance
         tol = dict(rtol=5e-2, atol=5e-2)
-    np.testing.assert_allclose(np.asarray(ker), np.asarray(ref), **tol)
+    # an idle row attends over nothing: the kernel writes zeros (the
+    # reference's softmax over no position is undefined)
+    live = np.asarray(lens) > 0
+    np.testing.assert_array_equal(np.asarray(ker)[~live], 0.0)
+    np.testing.assert_allclose(np.asarray(ker)[live], np.asarray(ref)[live],
+                               **tol)
+
+
+@pytest.mark.parametrize("kv", ["float", "int8"])
+@pytest.mark.parametrize("tail", ["sentinel", "reserved"])
+def test_kernel_never_reads_dead_pages(rng, kv, tail):
+    """Every page a row's table points at past its length, and page nb-1
+    where sentinels clamp, holds NaN: the kernel's output stays finite and
+    equal to the reference on the clean pool, and is bit-equal whether
+    the dead entries are sentinels, reserved pages or other rows' live
+    pages."""
+    B, nkv, g, hd, bs, P = 4, 2, 2, 16, 8, 4
+    lens = np.asarray([1, 2 * bs, 3 * bs + 3, 0], np.int32)
+    q, kp, vp, tab, lens = _random_case(rng, B, nkv, g, hd, bs, P,
+                                        lens=lens, tail=tail)
+    nb = kp.shape[0]
+    dead = _dead_entries(tab, lens, bs)
+    poisoned = np.unique(np.append(np.minimum(np.asarray(tab)[dead], nb - 1),
+                                   nb - 1))
+    live_pages = np.asarray(tab)[~dead]
+    assert not np.isin(poisoned, live_pages).any()
+    # the same rows with their dead entries aimed at live pages instead
+    aliased = np.where(dead, rng.choice(live_pages, size=tab.shape), tab)
+
+    if kv == "float":
+        ref = paged_attention_reference(q, kp, vp, tab, lens)
+        kp, vp = kp.at[poisoned].set(jnp.nan), vp.at[poisoned].set(jnp.nan)
+        kw, tol = {}, dict(rtol=1e-5, atol=1e-5)
+    else:
+        kp, vp, kw = _quantize_case(kp, vp)
+        ref = paged_attention_reference(q, kp, vp, tab, lens, **kw)
+        # int8 codes hold no NaN: poison the dead pages' scales
+        kw = {name: sc.at[poisoned].set(jnp.nan) for name, sc in kw.items()}
+        kw["assert_bounds"] = True
+        tol = dict(rtol=5e-2, atol=5e-2)
+
+    ker = np.asarray(paged_decode_attention(q, kp, vp, tab, lens,
+                                            interpret=True, **kw))
+    assert np.isfinite(ker).all()
+    live = np.asarray(lens) > 0
+    np.testing.assert_array_equal(ker[~live], 0.0)
+    np.testing.assert_allclose(ker[live], np.asarray(ref)[live], **tol)
+    ker_aliased = paged_decode_attention(q, kp, vp, jnp.asarray(aliased),
+                                         lens, interpret=True, **kw)
+    np.testing.assert_array_equal(np.asarray(ker_aliased), ker)
 
 
 @pytest.mark.parametrize("kv", ["float", "int8"])
