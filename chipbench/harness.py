@@ -15,7 +15,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
-from chipbench import check, recorder, traffic, weights
+from chipbench import arch, check, recorder, traffic, weights
 
 #: uids of the warm-up requests, clear of the window's
 WARM_UID = 1 << 30
@@ -64,7 +64,7 @@ def engine_for(params, cfg: dict, mix: dict, seed: int):
                         chunk_max=mix.get("chunk_max", 32),
                         kv_dtype=srv["kv_dtype"],
                         sched=SchedulerPolicy(**pol))
-    return PagedEngine(params, weights.model_config(cfg), paged,
+    return PagedEngine(params, arch.of(cfg).model_config(cfg), paged,
                        SamplerConfig(temperature=0.0, seed=int(seed) % 2**31))
 
 
@@ -285,6 +285,19 @@ def device_info() -> dict:
             "count": len(d)}
 
 
+def read_trace(ctx, trace_dir) -> None:
+    """A traced run's trace, read once into the readers' context: the
+    reduced timelines, the ops' name stacks and the engine counters of
+    the traced passes."""
+    from chipbench import scopes
+    from chipbench import trace as tracing
+
+    path = tracing.find(trace_dir)
+    ctx.trace = tracing.reduce(path)
+    ctx.scopes = scopes.read(path)
+    ctx.counters = scopes.pass_counters(path)
+
+
 def run(cell: dict, *, seed: int, seconds: float, trace: bool,
         t_start: float, root: Path, log=print) -> dict:
     cfg, mix, limits = cell["cfg"], cell["mix"], cell["limits"]
@@ -340,7 +353,6 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool,
         c["value"] is not None and c["value"] <= c["limit"]
         for c in checks.values())
     from chipbench import metrics
-    from chipbench import trace as tracing
 
     ctx = metrics.Ctx(cell=cell["cell"], cfg=cfg, mix=mix, seconds=seconds,
                       rec=rec, spans=spans, compiles=n_compiles,
@@ -350,7 +362,7 @@ def run(cell: dict, *, seed: int, seconds: float, trace: bool,
     dev = {**device_info(), "memory_peak_bytes": peak}
     breakdown = None
     if trace:
-        ctx.trace = tracing.reduce_dir(trace_dir)
+        read_trace(ctx, trace_dir)
         dev["busy_s"] = ctx.trace.busy_s
         dev["window_s"] = ctx.trace.window_s
         breakdown = ctx.trace.breakdown()

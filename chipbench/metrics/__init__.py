@@ -18,7 +18,8 @@ HERE = Path(__file__).resolve().parent
 @dataclass
 class Ctx:
     """What a reader may read: the cell, the run's records, the reduced
-    trace (traced runs), the chip's peaks."""
+    trace, the ops' name stacks and the engine counters (traced runs), the
+    chip's peaks."""
 
     cell: dict  # the BENCHMARK.json workload entry
     cfg: dict  # configs/<config>.json
@@ -30,6 +31,8 @@ class Ctx:
     peak_bytes: int
     setup_s: float
     trace: object = None  # trace.Reduced
+    scopes: dict | None = None  # scopes.read: name stack of each device op
+    counters: dict | None = None  # scopes.pass_counters: traced passes
     peaks: dict = field(default_factory=dict)
 
 

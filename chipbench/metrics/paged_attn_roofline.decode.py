@@ -1,6 +1,7 @@
 """Paged attention inside the decode chunks: the least time its calls
 could take, with the KV bytes of each row's live context (never the block
-table's width), over the device time its events took, in percent."""
+table's width) in every layer that holds attention, over the device time
+its events took, in percent."""
 
 from chipbench import roofline
 
@@ -21,7 +22,7 @@ def read(ctx):
             ops, byt = ops + o, byt + b
     if secs <= 0 or byt == 0:
         return None
-    layers = ctx.cfg["num_hidden_layers"]
+    layers = roofline.attn_layers(ctx.cfg)
     least = max(byt * layers / ctx.peaks["hbm_bytes_per_s"],
                 ops * layers / ctx.peaks["bf16_flops"])
     return 100.0 * least / secs

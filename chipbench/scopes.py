@@ -15,15 +15,13 @@ The fields read (``xplane.proto``): ``XSpace.planes`` = 1;
 ``XStatMetadata.name`` = 2; ``XStat.metadata_id`` = 1, ``.str_value`` = 5,
 ``.ref_value`` = 7 (a string held as the name of a stat metadata entry).
 
-The harness that calls the metric readers keeps the trace of a traced run
-at ``<checkout>/chiprun_out/chipbench/trace``; :func:`stacks_of` finds it
-there, and :func:`counters_of` reads the engine counters that ride on the
-trace's ``serve/pass`` spans.
+The harness reads both from a traced run's trace once, into the metric
+readers' context (``Ctx.scopes``, ``Ctx.counters``): the name stacks, and
+the engine counters that ride on the trace's ``serve/pass`` spans.
 """
 
 from __future__ import annotations
 
-import glob
 from pathlib import Path
 
 #: the device plane whose ops the per-layer metrics read
@@ -179,40 +177,15 @@ def pass_counters(path) -> dict:
     return {k: float(last[k]) - float(first[k]) for k in COUNTERS}
 
 
-def run_trace(root: Path | None = None) -> str | None:
-    """The ``.xplane.pb`` of the traced run in this checkout, where the
-    harness writes it (``<root>/chiprun_out/chipbench/trace``; the root is
-    the working directory when it holds ``BENCHMARK.json``, as ``run.py``
-    finds it), or None."""
-    if root is None:
-        root = Path.cwd()
-        if not (root / "BENCHMARK.json").is_file():
-            root = Path(__file__).resolve().parent.parent
-    hits = sorted(glob.glob(str(root / "chiprun_out" / "chipbench" / "trace"
-                                / "plugins" / "profile" / "*"
-                                / "*.xplane.pb")))
-    return hits[-1] if hits else None
-
-
-def _run_trace_of(ctx) -> str | None:
-    """The traced run's trace file, for a context of a traced run."""
-    return None if getattr(ctx, "trace", None) is None else run_trace()
-
-
 def stacks_of(ctx) -> dict:
-    """The name stacks a metric reader reads: those the context carries
-    (``ctx.scopes``, where set), else the traced run's. Empty for an
-    untraced run or where none is found."""
-    if getattr(ctx, "scopes", None) is not None:
-        return ctx.scopes
-    path = _run_trace_of(ctx)
-    return read(path) if path else {}
+    """The name stacks a metric reader reads: the traced run's
+    (``ctx.scopes``, which the harness fills). Empty for an untraced run
+    or where none is found."""
+    return ctx.scopes or {}
 
 
 def counters_of(ctx) -> dict:
-    """The engine counters over the traced passes, as :func:`stacks_of`
-    finds the name stacks (``ctx.counters`` where set)."""
-    if getattr(ctx, "counters", None) is not None:
-        return ctx.counters
-    path = _run_trace_of(ctx)
-    return pass_counters(path) if path else {}
+    """The engine counters over the traced passes (``ctx.counters``, which
+    the harness fills). Empty for an untraced run or where none is
+    found."""
+    return ctx.counters or {}

@@ -1,6 +1,6 @@
 """Seeded float weights of a dense decoder, one layer at a time.
 
-Both the served model (``weights.py`` packs these) and the plain reference
+Both the served model (``arch/dense.py`` packs these) and the plain reference
 (``references/dense.py``) draw their weights from here, so neither takes
 anything the program made. Every layer comes from its own key, so the
 float model never has to exist whole: a layer is drawn, used and dropped.

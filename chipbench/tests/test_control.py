@@ -11,12 +11,12 @@ import functools
 
 import numpy as np
 
-from chipbench import check, weights
+from chipbench import arch, check, weights
 from repro.models.layers import use_packed_backend
 from repro.serving import PagedConfig, PagedEngine, SamplerConfig
 from repro.serving.scheduler import Request
 
-CFG = dict(name="tiny", reference="dense", num_hidden_layers=2,
+CFG = dict(name="tiny", arch="dense", reference="dense", num_hidden_layers=2,
            hidden_size=128, intermediate_size=256, num_attention_heads=2,
            num_key_value_heads=1, vocab_size=512,
            max_position_embeddings=512, rope_theta=1e4, rms_norm_eps=1e-6,
@@ -28,7 +28,7 @@ CFG = dict(name="tiny", reference="dense", num_hidden_layers=2,
 def served(seed: int) -> tuple:
     params = weights.packed_params(CFG, seed)
     engine = PagedEngine(
-        params, weights.model_config(CFG),
+        params, arch.of(CFG).model_config(CFG),
         PagedConfig(block_size=64, num_blocks=16, max_concurrency=4,
                     max_pages_per_seq=2, attn_impl="interpret"),
         SamplerConfig(temperature=0.0))

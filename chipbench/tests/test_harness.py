@@ -9,7 +9,7 @@ import pytest
 
 from chipbench import harness, run
 
-TINY = dict(name="tiny", reference="dense", num_hidden_layers=2,
+TINY = dict(name="tiny", arch="dense", reference="dense", num_hidden_layers=2,
             hidden_size=128, intermediate_size=256, num_attention_heads=4,
             num_key_value_heads=2, vocab_size=512,
             max_position_embeddings=512, rope_theta=10000.0,

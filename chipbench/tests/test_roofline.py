@@ -3,7 +3,8 @@ from pathlib import Path
 
 import pytest
 
-from chipbench import metrics, roofline, weights
+from chipbench import arch, metrics, roofline
+from repro.models.config import param_count
 from repro.serving.scheduler import kv_page_bytes
 from repro.quant.serve_packed import packed_weight_bytes
 
@@ -15,28 +16,48 @@ def _cfg(path):
     return json.loads(Path(path).read_text())
 
 
+def _active_matmul_params(mcfg) -> int:
+    """The program's count of the weights a token multiplies through: its
+    active parameters less the norms' gains, and less the embedding table
+    where the head is not tied to it (a lookup, no matmul)."""
+    n = param_count(mcfg)["active"] - (2 * mcfg.n_layers + 1) * mcfg.d_model
+    return n if mcfg.tie_embeddings else n - mcfg.vocab * mcfg.d_model
+
+
 @pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
 def test_weight_bytes_match_the_program(path):
     cfg = _cfg(path)
-    pw = packed_weight_bytes(weights.model_config(cfg))
-    ours = sum(roofline.w4a8_weight_bytes(k, n)
-               for _, k, n in roofline.sites(cfg)) * cfg["num_hidden_layers"]
+    a = arch.of(cfg)
+    mcfg = a.model_config(cfg)
+    pw = packed_weight_bytes(mcfg)
+    ours = sum(roofline.w4a8_weight_bytes(k, n) for _, k, n in a.sites(cfg))
     assert ours == (pw["packed_code_bytes"] + pw["scale_bytes"]
                     + pw["col_sums_bytes"])
-    assert roofline.matmul_params(cfg) == pw["weight_elems"] + (
-        cfg["vocab_size"] * cfg["hidden_size"])
+    assert a.matmul_params(cfg) == _active_matmul_params(mcfg)
 
 
 @pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
 @pytest.mark.parametrize("kv", ["act", "int8"])
 def test_kv_bytes_match_the_program(path, kv):
     cfg = _cfg(path)
-    mcfg = weights.model_config(cfg)
-    bs, pages, L = 64, 5, cfg["num_hidden_layers"]
-    d = cfg["hidden_size"]
+    a = arch.of(cfg)
+    mcfg = a.model_config(cfg)
+    bs, pages = 64, 5
     _, byt = roofline.attn_call(cfg, [pages * bs], kv, block=bs)
-    q_out = 2 * 2 * d
-    assert byt - q_out == pages * kv_page_bytes(mcfg, bs, kv) / L
+    q_out = 2 * 2 * a.attn_heads(cfg)[0] * a.head_dim(cfg)
+    assert byt - q_out == pages * kv_page_bytes(mcfg, bs, kv) / (
+        roofline.attn_layers(cfg))
+
+
+@pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
+def test_attention_shape_matches_the_program(path):
+    cfg = _cfg(path)
+    a = arch.of(cfg)
+    mcfg = a.model_config(cfg)
+    assert a.head_dim(cfg) == mcfg.head_dim
+    assert a.attn_heads(cfg) == (mcfg.n_heads, mcfg.n_kv_heads)
+    assert a.attn_layers(cfg) == mcfg.repeats * sum(
+        s.mixer == "attn" for s in mcfg.pattern)
 
 
 def test_attention_counts_live_length_not_table_width():
@@ -62,8 +83,9 @@ def test_peaks_known_and_unknown():
 
 def test_prefill_ops_split_into_chunks_add_up():
     cfg = _cfg(CONFIGS[0])
-    head = 2.0 * cfg["vocab_size"] * cfg["hidden_size"]
-    whole = roofline.prefill_ops(cfg, 0, 1024)
-    parts = roofline.prefill_ops(cfg, 0, 512) + roofline.prefill_ops(
-        cfg, 512, 1024)
+    a = arch.of(cfg)
+    mcfg = a.model_config(cfg)
+    head = 2.0 * mcfg.vocab * mcfg.d_model
+    whole = a.prefill_ops(cfg, 0, 1024)
+    parts = a.prefill_ops(cfg, 0, 512) + a.prefill_ops(cfg, 512, 1024)
     assert parts - head == pytest.approx(whole)

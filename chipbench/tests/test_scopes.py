@@ -11,7 +11,7 @@ from pathlib import Path
 
 import pytest
 
-from chipbench import metrics, scopes, trace
+from chipbench import harness, metrics, scopes, trace
 from chipbench.trace import Event, Reduced
 
 FIXTURES = Path(__file__).resolve().parent / "fixtures"
@@ -72,14 +72,10 @@ def test_under_is_a_whole_component():
 
 
 def _ctx(red=None, stacks=None, counters=None):
-    ctx = metrics.Ctx(cell={"name": "t"}, cfg={}, mix={}, seconds=1.0,
-                      rec=None, spans=None, compiles=0, peak_bytes=0,
-                      setup_s=0.0, trace=red)
-    if stacks is not None:
-        ctx.scopes = stacks
-    if counters is not None:
-        ctx.counters = counters
-    return ctx
+    return metrics.Ctx(cell={"name": "t"}, cfg={}, mix={}, seconds=1.0,
+                       rec=None, spans=None, compiles=0, peak_bytes=0,
+                       setup_s=0.0, trace=red, scopes=stacks,
+                       counters=counters)
 
 
 SCAN = "jit(_chunk)/while/body/layer_scan/while/body/"
@@ -166,8 +162,8 @@ def test_counter_readers(name, want):
 def test_counters_ride_on_the_pass_spans(tmp_path, monkeypatch):
     """The engine serving under the profiler here (CPU): the counters its
     ``serve/pass`` spans carry give, through the trace alone, the counters
-    of the passes between the first span and the last; a reader finds the
-    trace where the harness leaves it."""
+    of the passes between the first span and the last; the harness reads
+    them from the trace where it leaves it into the readers' context."""
     import jax
     import numpy as np
 
@@ -209,17 +205,17 @@ def test_counters_ride_on_the_pass_spans(tmp_path, monkeypatch):
         assert got[k] == pytest.approx(snaps[-2][k] - start[k]), k
     assert 0 < got["readback_s"] < got["pass_s"]
 
-    root = tmp_path / "checkout"
-    dest = root / "chiprun_out" / "chipbench" / "trace" / "plugins" \
-        / "profile" / "1"
+    trace_dir = tmp_path / "trace"
+    dest = trace_dir / "plugins" / "profile" / "1"
     dest.mkdir(parents=True)
     shutil.copy(path, dest / "host.xplane.pb")
-    (root / "BENCHMARK.json").write_text(json.dumps({}))
-    monkeypatch.chdir(root)
-    assert scopes.run_trace() == str(dest / "host.xplane.pb")
-    assert scopes.stacks_of(_ctx(Reduced())) == {}  # no device plane here
-    assert scopes.counters_of(_ctx(Reduced())) == got
-    assert metrics.reader("attn_page_use.decode")(_ctx(Reduced())) == \
+    # a CPU trace holds no device plane to reduce
+    monkeypatch.setattr(trace, "reduce", lambda p: Reduced())
+    ctx = _ctx(None)
+    harness.read_trace(ctx, trace_dir)
+    assert ctx.scopes == {}  # no device plane here
+    assert scopes.counters_of(ctx) == got
+    assert metrics.reader("attn_page_use.decode")(ctx) == \
         pytest.approx(100 * got["attn_pages_live"] / got["attn_pages_grid"])
     assert scopes.stacks_of(_ctx(None)) == {}
     assert scopes.counters_of(_ctx(None)) == {}

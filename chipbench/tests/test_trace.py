@@ -2,6 +2,7 @@
 a small trace recorded on a TPU v5e (``fixtures/``)."""
 
 import json
+import shutil
 from pathlib import Path
 
 import pytest
@@ -67,7 +68,7 @@ def test_recorded_chip_trace(case):
 
     red = trace.reduce(str(FIXTURES / case / "trace.xplane.pb"))
     spans = json.loads((FIXTURES / case / "spans.json").read_text())
-    cfg = spans["cfg"]
+    cfg = {**spans["cfg"], "arch": "dense"}
     steps = sum(c[1] for c in spans["chunks"])
     layers = cfg["num_hidden_layers"]
     chunk_ops = red.inside(red.ops, "chunk")
@@ -95,3 +96,81 @@ def test_recorded_chip_trace(case):
                  "decode_mfu", "device_idle.decode"):
         value = metrics.reader(name)(ctx)
         assert value is not None and 0 < value < 100, (name, value)
+
+
+#: what every per-layer reader read on each recorded trace before the
+#: model's counts moved into ``arch/`` and the harness read the name
+#: stacks and counters into the context (None: nothing to read there)
+RECORDED = {
+    "decode-bf16-pages": {
+        "w4a8_roofline.decode": 9.33868453257807,
+        "paged_attn_roofline.decode": 5.720552268985488,
+        "decode_mfu": 0.09820361795514922,
+        "device_idle.decode": 40.701589098239324,
+        "compiles_in_window.decode": 0.0,
+        "layer_scan_overhead.decode": None,
+        "attn_page_use.decode": None,
+        "host_loop_share.decode": None,
+    },
+    "decode-int8-scoped": {
+        "w4a8_roofline.decode": 9.338712730015207,
+        "paged_attn_roofline.decode": 0.40296701564519516,
+        "decode_mfu": 0.07567369357639418,
+        "device_idle.decode": 36.18159366918556,
+        "compiles_in_window.decode": 0.0,
+        "layer_scan_overhead.decode": 5.072786736918658,
+        "attn_page_use.decode": 12.5,
+        "host_loop_share.decode": 22.661906720548544,
+    },
+    "longctx-int8-pages": {
+        "w4a8_roofline.decode": 9.100425999879457,
+        "paged_attn_roofline.decode": 2.6347740395561146,
+        "decode_mfu": 0.0181125191109267,
+        "device_idle.decode": 45.196921005348415,
+        "compiles_in_window.decode": 0.0,
+        "layer_scan_overhead.decode": None,
+        "attn_page_use.decode": None,
+        "host_loop_share.decode": None,
+    },
+}
+
+
+@pytest.fixture(scope="module")
+def traced_ctx(tmp_path_factory):
+    """The readers' context of a recorded trace, as the harness fills it
+    from a traced run's trace directory."""
+    from chipbench import harness, metrics
+
+    made = {}
+
+    def get(case):
+        if case not in made:
+            spans = json.loads((FIXTURES / case / "spans.json").read_text())
+            trace_dir = tmp_path_factory.mktemp(case)
+            dest = trace_dir / "plugins" / "profile" / "1"
+            dest.mkdir(parents=True)
+            shutil.copy(FIXTURES / case / "trace.xplane.pb",
+                        dest / "host.xplane.pb")
+
+            class Spans:
+                rows = spans["rows"]
+                chunks = [tuple(c) for c in spans["chunks"]]
+                prefills = [tuple(p) for p in spans["prefills"]]
+
+            ctx = metrics.Ctx(cell={"name": case},
+                              cfg={**spans["cfg"], "arch": "dense"}, mix={},
+                              seconds=1.0, rec=None, spans=Spans, compiles=0,
+                              peak_bytes=0, setup_s=0.0,
+                              peaks=metrics.peaks("TPU v5 lite"))
+            harness.read_trace(ctx, trace_dir)
+            made[case] = ctx
+        return made[case]
+    return get
+
+
+@pytest.mark.parametrize("case,name", [(c, n) for c in sorted(RECORDED)
+                                       for n in RECORDED[c]])
+def test_readers_read_what_they_read_before(traced_ctx, case, name):
+    from chipbench import metrics
+
+    assert metrics.reader(name)(traced_ctx(case)) == RECORDED[case][name]

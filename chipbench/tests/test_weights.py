@@ -1,14 +1,19 @@
+import json
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import seeded, weights
+from chipbench import arch, seeded, weights
 from chipbench.references import dense
 from repro.kernels.w4a8_mm import unpack_int4
 from repro.quant.serve_packed import pack_decode_params
 
-SMOKE = dict(name="smoke", num_hidden_layers=3, hidden_size=128,
+CONFIGS = sorted((Path(__file__).resolve().parents[1] / "configs").glob(
+    "*.json"))
+SMOKE = dict(name="smoke", arch="dense", num_hidden_layers=3, hidden_size=128,
              num_attention_heads=4, num_key_value_heads=2,
              intermediate_size=256, vocab_size=512, rms_norm_eps=1e-6,
              rope_theta=1e4, max_position_embeddings=512,
@@ -16,17 +21,31 @@ SMOKE = dict(name="smoke", num_hidden_layers=3, hidden_size=128,
              datapath=dict(w_bits=4, act_bits=8, p_bits=16, tile=128))
 
 
+def assert_build_equals_whole_model_packing(cfg: dict, seed: int):
+    a = arch.of(cfg)
+    built = weights.packed_params(cfg, seed)
+    whole = jax.jit(lambda k: pack_decode_params(
+        a.float_params(k, cfg), a.model_config(cfg),
+        weights.ptq_config(cfg)))(seeded.seed_key(seed))
+    x, tx = jax.tree.flatten(built)
+    y, ty = jax.tree.flatten(whole)
+    assert tx == ty
+    for u, v in zip(x, y):
+        assert np.array_equal(np.asarray(u), np.asarray(v), equal_nan=True)
+
+
 @pytest.mark.parametrize("seed", [0, 2**31 + 9, 2**40 + 3])
 def test_layer_at_a_time_build_equals_whole_model_packing(seed):
-    built = weights.packed_params(SMOKE, seed)
-    whole = jax.jit(lambda k: pack_decode_params(
-        weights.float_params(k, SMOKE), weights.model_config(SMOKE),
-        weights.ptq_config(SMOKE)))(seeded.seed_key(seed))
-    a, ta = jax.tree.flatten(built)
-    b, tb = jax.tree.flatten(whole)
-    assert ta == tb
-    for x, y in zip(a, b):
-        assert np.array_equal(np.asarray(x), np.asarray(y), equal_nan=True)
+    assert_build_equals_whole_model_packing(SMOKE, seed)
+
+
+@pytest.mark.parametrize("path", CONFIGS, ids=lambda p: p.stem)
+def test_each_configs_build_equals_whole_model_packing(path):
+    """Every configuration's architecture, cut by its own ``small`` to a
+    size the CPU holds."""
+    cfg = json.loads(path.read_text())
+    assert_build_equals_whole_model_packing(arch.of(cfg).small(cfg),
+                                            2**33 + 17)
 
 
 def test_seeds_draw_different_weights():

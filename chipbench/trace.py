@@ -191,10 +191,6 @@ def find(trace_dir) -> str:
     return hits[-1]
 
 
-def reduce_dir(trace_dir) -> Reduced:
-    return reduce(find(trace_dir))
-
-
 def inventory(path: str, n: int = 25) -> dict:
     """Every plane and line of a trace, with event counts and the names
     that took most time: for reading a trace by hand."""
